@@ -132,6 +132,9 @@ def main() -> None:
             print(f"{mod.NAME}\t{mod.PAPER_REF}")
         return
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     if args.protocol:
         import re
         from benchmarks.common import save_rows

@@ -115,8 +115,9 @@ def _sweep(quick: bool):
     import numpy as np
 
     n_dev = len(jax.devices())
+    platform = jax.devices()[0].platform
     rows = [{
-        "layout": "meta", "visible_devices": n_dev,
+        "layout": "meta", "platform": platform, "visible_devices": n_dev,
         "cores": os.cpu_count(),
         "scaling_expected": (os.cpu_count() or 1) >= 4,
         "note": ("forced host devices share the runner's cores; "
@@ -151,6 +152,7 @@ def _sweep(quick: bool):
     for arch_smoke, m, rounds, batch, reps, devs in cases:
         base_row, base_comm, base_xf, base_dl = _engine(
             arch_smoke, m, "flat", 0, rounds, batch, reps)
+        base_row["platform"] = platform
         rows.append(base_row)
         del base_dl
         one_dev_rps = None
@@ -158,6 +160,7 @@ def _sweep(quick: bool):
         for d in sweep:
             row, comm, xf, dl = _engine(
                 arch_smoke, m, "sharded", d, rounds, batch, reps)
+            row["platform"] = platform
             row["counters_equal"] = bool(
                 comm == base_comm and np.array_equal(xf, base_xf))
             if d == 1:
@@ -201,13 +204,16 @@ def _chunk_batch(arch_smoke, m, batch):
 def run(quick: bool = True):
     import jax
 
-    if len(jax.devices()) >= FORCED_DEVICES:
+    if (jax.default_backend() == "cpu"
+            and len(jax.devices()) >= FORCED_DEVICES):
         rows = _sweep(quick)
     else:
-        # jax is already initialized with too few devices — the forced
-        # device count only takes effect before init, so re-exec the
-        # sweep in a child process
+        # a forced-host correctness sweep: the device count only takes
+        # effect before jax initializes, so re-exec the sweep in a child
+        # held to the CPU — it never needs (or takes) a chip this process
+        # may hold
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                             f" --xla_force_host_platform_device_count="
                             f"{FORCED_DEVICES}").strip()

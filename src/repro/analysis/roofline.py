@@ -4,6 +4,9 @@
     memory term     = HLO_bytes_per_chip / HBM_bw
     collective term = wire_bytes_per_chip / link_bw
 
+The peaks come from ``CHIP_PEAKS``, one table keyed by the chip's
+``device_kind`` with its source.
+
 ``cost_analysis()`` on the compiled executable reports the per-device
 (post-SPMD-partition) module, so its flops/bytes are already per chip;
 collective wire bytes come from ``repro.analysis.hlo``. MODEL_FLOPS uses
@@ -14,10 +17,36 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import NamedTuple, Optional
 
 from repro.analysis import hlo as hlo_mod
-from repro.launch.mesh import HBM_BW, ICI_BW_PER_LINK, PEAK_FLOPS_BF16
+
+
+class ChipPeaks(NamedTuple):
+    """Published per-chip peak rates: the roofline's denominators."""
+    flops_bf16: float          # FLOP/s
+    hbm_bw: float              # bytes/s
+    ici_bw_per_link: float     # bytes/s per interchip link
+
+
+# Keyed by ``jax.Device.device_kind``. Source: Google Cloud TPU
+# documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s
+# of interchip interconnect per chip over 4 links (50 GB/s each).
+CHIP_PEAKS = {
+    "TPU v5 lite": ChipPeaks(flops_bf16=197e12, hbm_bw=819e9,
+                             ici_bw_per_link=50e9),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The peaks of ``device_kind``; a kind not in the table is an error,
+    never a default."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device_kind {device_kind!r}; "
+            f"known: {sorted(CHIP_PEAKS)}") from None
 
 
 @dataclass
@@ -57,21 +86,21 @@ def model_flops_for(cfg, shape, mode: str) -> float:
     return 2.0 * n * shape.global_batch
 
 
-def analyze(name: str, compiled, num_devices: int,
-            model_flops: Optional[float] = None,
-            peak_flops: float = PEAK_FLOPS_BF16,
-            hbm_bw: float = HBM_BW,
-            link_bw: float = ICI_BW_PER_LINK) -> RooflineReport:
+def analyze(name: str, compiled, num_devices: int, device_kind: str,
+            model_flops: Optional[float] = None) -> RooflineReport:
+    """Roofline of a compiled program on ``num_devices`` chips of
+    ``device_kind`` (peaks from :data:`CHIP_PEAKS`)."""
     from repro.compat import cost_analysis
+    peaks = chip_peaks(device_kind)
     ca = cost_analysis(compiled)
     flops = float(ca.get("flops", 0.0))
     bytes_acc = float(ca.get("bytes accessed", 0.0))
     stats = hlo_mod.parse_collectives(compiled.as_text(), num_devices)
     wire = stats.total_wire_bytes
 
-    compute_s = flops / peak_flops
-    memory_s = bytes_acc / hbm_bw
-    collective_s = wire / link_bw
+    compute_s = flops / peaks.flops_bf16
+    memory_s = bytes_acc / peaks.hbm_bw
+    collective_s = wire / peaks.ici_bw_per_link
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     bottleneck = max(terms, key=terms.get)

@@ -1,150 +1,55 @@
-"""JAX version-portability layer (tested against jax 0.4.37; written for
-0.4.x - 0.6.x).
+"""The few jax entry points this repo wraps, written for jax 0.9.0 (the
+one installation it runs on, CPU and TPU alike).
 
-Every API this repo uses whose import path or signature moved between jax
-releases is resolved HERE, once, so call sites stay version-agnostic:
+* ``make_mesh`` / ``abstract_mesh`` — meshes with ``AxisType.Auto`` axes.
+  jax 0.9 makes ``Explicit`` axes by default; every sharding rule here
+  (``with_sharding_constraint`` pins, ``NamedSharding`` placement, the
+  fleet's ``shard_map``) is written for GSPMD's auto partitioning.
+* ``shard_map`` — ``jax.shard_map`` with the mesh passed by keyword,
+  unchecked.
+* ``cost_analysis`` — ``compiled.cost_analysis()`` as a dict (``{}``
+  where XLA reports nothing).
 
-=====================  ==========================  =========================
-API                    jax 0.4.x                   jax >= 0.5 / 0.6
-=====================  ==========================  =========================
-shard_map              jax.experimental.shard_map  jax.shard_map
-  replication check      ``check_rep=``              ``check_vma=`` (0.6)
-AbstractMesh           shape_tuple of              positional
-                       (name, size) pairs          (axis_sizes, axis_names)
-make_mesh              no ``axis_types``           ``axis_types=`` kwarg
-AxisType               absent                      jax.sharding.AxisType
-=====================  ==========================  =========================
-
-Import from here, never from jax directly, for any of the above:
+Import these from here, never from jax directly:
 
     from repro.compat import abstract_mesh, make_mesh, shard_map
 """
 from __future__ import annotations
 
-import inspect
-from typing import Any, Optional, Sequence, Tuple
+from typing import Sequence
 
 import jax
+from jax.sharding import AxisType
 
 
-def _parse_version(v: str) -> Tuple[int, ...]:
-    parts = []
-    for p in v.split(".")[:3]:
-        digits = "".join(ch for ch in p if ch.isdigit())
-        parts.append(int(digits) if digits else 0)
-    return tuple(parts)
-
-
-JAX_VERSION: Tuple[int, ...] = _parse_version(jax.__version__)
-
-# ---------------------------------------------------------------------------
-# AxisType (jax >= 0.5): None on older releases. Callers must treat it as
-# optional — ``default_axis_types`` below is the portable entry point.
-# ---------------------------------------------------------------------------
-
-AxisType = getattr(jax.sharding, "AxisType", None)
-HAS_AXIS_TYPE = AxisType is not None
-
-
-def default_axis_types(n_axes: int) -> Optional[tuple]:
-    """(AxisType.Auto,) * n on jax >= 0.5; None (omit the kwarg) before."""
-    if AxisType is None:
-        return None
+def _auto(n_axes: int) -> tuple:
     return (AxisType.Auto,) * n_axes
 
 
-# ---------------------------------------------------------------------------
-# make_mesh
-# ---------------------------------------------------------------------------
-
 def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
-              axis_types: Any = "auto", devices=None) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with the ``axis_types`` kwarg applied only where the
-    installed jax supports it (>= 0.5). ``axis_types="auto"`` requests
-    Auto-typed axes when available and is silently dropped otherwise —
-    exactly the behaviour every pre-AxisType release had implicitly.
-    """
-    shape, axes = tuple(shape), tuple(axes)
-    if not hasattr(jax, "make_mesh"):           # jax < 0.4.35
-        import numpy as np
-        n = 1
-        for s in shape:
-            n *= s
-        devs = np.asarray(devices if devices is not None
-                          else jax.devices()[:n]).reshape(shape)
-        return jax.sharding.Mesh(devs, axes)
-    kw = {}
-    if devices is not None:
-        kw["devices"] = devices
-    if "axis_types" in inspect.signature(jax.make_mesh).parameters:
-        if axis_types == "auto":
-            axis_types = default_axis_types(len(axes))
-        if axis_types is not None:
-            kw["axis_types"] = axis_types
-    return jax.make_mesh(shape, axes, **kw)
+              devices=None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with Auto-typed axes."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=_auto(len(axes)), devices=devices)
 
-
-# ---------------------------------------------------------------------------
-# AbstractMesh
-# ---------------------------------------------------------------------------
 
 def abstract_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """Device-free mesh for sharding-rule evaluation, on every signature:
-    jax >= 0.5 takes ``(axis_sizes, axis_names)`` positionally; 0.4.x takes a
-    single ``shape_tuple`` of (name, size) pairs.
-    """
-    shape, axes = tuple(shape), tuple(axes)
-    AbstractMesh = jax.sharding.AbstractMesh
-    try:
-        return AbstractMesh(shape, axes)
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+    """Device-free mesh (Auto axes) for sharding-rule evaluation."""
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes),
+                                     axis_types=_auto(len(axes)))
 
-
-# ---------------------------------------------------------------------------
-# cost_analysis
-# ---------------------------------------------------------------------------
 
 def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict on every jax.
-
-    jax 0.4.x returns a LIST with one per-program dict; jax >= 0.5 returns
-    the dict directly; either may be None for trivial programs.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    return ca or {}
+    """``compiled.cost_analysis()``; ``{}`` for a program XLA does not
+    cost."""
+    return compiled.cost_analysis() or {}
 
 
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
+def shard_map(f, *, mesh, in_specs, out_specs):
+    """``jax.shard_map`` over ``mesh``, unchecked (``check_vma=False``).
 
-def _resolve_shard_map():
-    if hasattr(jax, "shard_map"):               # jax >= 0.6
-        return jax.shard_map
-    from jax.experimental.shard_map import shard_map as sm  # 0.4.x / 0.5.x
-    return sm
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: Optional[bool] = None,
-              **kwargs):
-    """Portable ``shard_map``.
-
-    ``check_vma`` is the jax >= 0.6 name for what 0.4.x/0.5.x call
-    ``check_rep`` (the replication/varying-manual-axes checker); pass the new
-    name here and it is translated to whatever the installed jax accepts.
-    """
-    sm = _resolve_shard_map()
-    params = inspect.signature(sm).parameters
-    kw = dict(kwargs)
-    if check_vma is not None:
-        if "check_vma" in params:
-            kw["check_vma"] = check_vma
-        elif "check_rep" in params:
-            kw["check_rep"] = check_vma
-        # else: the checker kwarg vanished entirely — nothing to forward.
-    if "mesh" in params and params["mesh"].kind is inspect.Parameter.KEYWORD_ONLY:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    return sm(f, mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    Every caller maps a row-local function — each device computes its
+    own rows — whose outputs are split over the mesh, so there is no
+    replicated output for the check to guard."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

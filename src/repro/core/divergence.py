@@ -22,6 +22,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.compat import shard_map
+from repro.core import shard
+
 
 def flat_size(tree) -> int:
     return sum(x.size for x in jax.tree.leaves(tree))
@@ -98,14 +101,25 @@ def per_learner_sq_distance_flat(X, r,
     (``repro.kernels.ops.sqdist_rows``); elsewhere the kernel would
     execute in interpret mode (Python, orders of magnitude slower than
     XLA), so the dense jnp row reduction is used instead.
-    ``use_kernel`` forces the choice either way."""
+    ``use_kernel`` forces the choice either way.
+
+    Under an active fleet (``layout="sharded"``) the kernel runs inside
+    a ``shard_map`` over the ``"fleet"`` axis: each device reduces its
+    own rows against the replicated reference, so the plane is never
+    gathered (a Mosaic kernel cannot be partitioned automatically)."""
     if use_kernel is None:
         use_kernel = jax.default_backend() == "tpu"
-    if use_kernel:
-        from repro.kernels import ops as kops
+    if not use_kernel:
+        d = X.astype(jnp.float32) - r.astype(jnp.float32)[None]
+        return jnp.sum(d * d, axis=1)
+    from repro.kernels import ops as kops
+    fleet = shard.current_fleet()
+    if fleet is None or X.shape[0] != fleet.m:
         return kops.sqdist_rows(X, r)
-    d = X.astype(jnp.float32) - r.astype(jnp.float32)[None]
-    return jnp.sum(d * d, axis=1)
+    P = jax.sharding.PartitionSpec
+    return shard_map(kops.sqdist_rows, mesh=fleet.mesh,
+                     in_specs=(P(shard.FLEET_AXIS, None), P()),
+                     out_specs=P(shard.FLEET_AXIS))(X, r)
 
 
 def per_learner_sq_distance(stacked, ref) -> jnp.ndarray:

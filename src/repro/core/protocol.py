@@ -337,6 +337,12 @@ class DecentralizedLearner:
             params, opt_state = opt.update(params, grads, opt_state)
             return params, opt_state, loss
 
+        # under a fleet each device updates its own learners: the vmapped
+        # update runs per shard, not GSPMD-partitioned
+        update = jax.vmap(local_update)
+        if fleet is not None:
+            update = shard.per_shard(fleet, update)
+
         def step(params, opt_state, sync_state, batches):
             t = (sync_state.step if tiers is None
                  else sync_state.inter.step)          # this round's index
@@ -357,8 +363,7 @@ class DecentralizedLearner:
                             sync_state.extra, restart, m))
             # availability means REACHABILITY: every learner still takes its
             # local SGD step; unavailable ones just cannot communicate
-            upd, opt_upd, losses = jax.vmap(local_update)(
-                params, opt_state, batches)
+            upd, opt_upd, losses = update(params, opt_state, batches)
             if faults is not None:
                 # a learner mid-outage is STATELESS, not just unreachable:
                 # its training freezes (the update is discarded) and it

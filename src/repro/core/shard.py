@@ -32,10 +32,11 @@ wrong); per-cluster sync then runs with flat arithmetic while the fleet
 carry around it stays device-sharded.
 
 Everything device-visible goes through the fleet's mesh, so the sharded
-layout executes per-shard: the per-learner update, ``sqdist_rows``, the
-``(m, P)`` commits and the per-link ledger rows are local to each
-device's row block, and only the trigger vote (an ``any()`` over (m,)
-scalars) and the cohort means (one ``w @ X`` matvec) cross devices.
+layout executes per-shard: the per-learner update and ``sqdist_rows``
+(each inside a ``shard_map``, :func:`per_shard`), the ``(m, P)`` commits
+and the per-link ledger rows are local to each device's row block, and
+only the trigger vote (an ``any()`` over (m,) scalars) and the cohort
+means (one ``w @ X`` matvec) cross devices.
 """
 from __future__ import annotations
 
@@ -47,7 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
-from repro.compat import make_mesh
+from repro.compat import make_mesh, shard_map
 
 FLEET_AXIS = "fleet"
 
@@ -154,6 +155,17 @@ def constrain_fleet(fleet: FleetSharding, tree, axis: int = 0):
         return jax.lax.with_sharding_constraint(
             x, fleet.row_sharding(jnp.ndim(x), axis))
     return jax.tree.map(pin, tree)
+
+
+def per_shard(fleet: FleetSharding, fn):
+    """Run ``fn`` on each device's own learner rows, with no partitioner
+    in between: every argument and output leaf is split over ``"fleet"``
+    along its leading (learner) axis, and ``fn`` sees the local
+    ``m / n_devices`` rows. For row-local work — the vmapped per-learner
+    update — this is the sharded layout's whole contract, spelled out
+    instead of left to GSPMD."""
+    rows = PartitionSpec(FLEET_AXIS)
+    return shard_map(fn, mesh=fleet.mesh, in_specs=rows, out_specs=rows)
 
 
 # The compiled round (core/sync/spec.py) is cached per ProtocolSpec and

@@ -76,7 +76,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         "causal", "window", "scale", "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     scale: float | None = None, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128, interpret: bool):
     """q: (B, Sq, d), k/v: (B, Sk, d) -> (B, Sq, d).
 
     Sq may be shorter than Sk (the causal diagonal is right-aligned, as in

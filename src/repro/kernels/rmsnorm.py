@@ -23,7 +23,7 @@ def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
 @functools.partial(jax.jit,
                    static_argnames=("eps", "block_rows", "interpret"))
 def rmsnorm(x, scale, *, eps: float = 1e-5, block_rows: int = 128,
-            interpret: bool = True):
+            interpret: bool):
     """x: (..., D), scale: (D,). Rows processed in (block_rows, D) tiles."""
     orig_shape = x.shape
     d = x.shape[-1]

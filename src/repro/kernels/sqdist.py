@@ -38,7 +38,7 @@ def _sqdist_kernel(x_ref, r_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def sqdist(x, r, *, block: int = 65536, interpret: bool = True):
+def sqdist(x, r, *, block: int = 65536, interpret: bool):
     """||x - r||^2 over flattened inputs. Pads to a block multiple with
     equal values (zero contribution)."""
     xf = x.reshape(-1)
@@ -76,26 +76,62 @@ def _sqdist_rows_kernel(x_ref, r_ref, o_ref):
     o_ref[...] += jnp.sum(d * d, axis=1, keepdims=True)
 
 
+# Mosaic's tiling rule: the last two dims of a block are multiples of
+# (8, 128) or equal the array's dims. Of the 16 MiB of VMEM Mosaic grants
+# a kernel by default, the double-buffered tiles may take this much.
+_SUBLANES, _LANES = 8, 128
+_VMEM_BUDGET = 12 * 2**20
+
+
+def _row_block(m: int, block_m: int) -> int:
+    """The row tile the chip accepts: all ``m`` rows when they fit one
+    block (a block equal to the full dim is always legal), else
+    ``block_m`` rounded up to the sublane multiple of 8. A ragged last
+    row block is a partial edge block: its out-of-range rows read
+    unspecified values into output rows that are never written back."""
+    bm = -(-block_m // _SUBLANES) * _SUBLANES
+    return m if m <= bm else bm
+
+
+def _check_tiles(bm: int, block: int, x_dtype, r_dtype) -> None:
+    if block % _LANES:
+        raise ValueError(
+            f"sqdist_rows: block={block} must be a multiple of {_LANES} "
+            f"(the TPU lane width)")
+
+    def tile_bytes(rows, dtype):
+        item = jnp.dtype(dtype).itemsize
+        sub = _SUBLANES * max(1, 4 // item)      # packed sublanes
+        return -(-rows // sub) * sub * block * item
+
+    need = 2 * (tile_bytes(bm, x_dtype) + tile_bytes(1, r_dtype))
+    if need > _VMEM_BUDGET:
+        raise ValueError(
+            f"sqdist_rows: a ({bm}, {block}) tile double-buffered needs "
+            f"{need} bytes of VMEM, over the {_VMEM_BUDGET}-byte budget; "
+            f"pass a smaller block_m or block")
+
+
 @functools.partial(jax.jit,
                    static_argnames=("block_m", "block", "interpret"))
 def sqdist_rows(x, r, *, block_m: int = 8, block: int = 65536,
-                interpret: bool = True):
+                interpret: bool):
     """Row-wise ``||x[i] - r||^2``: x ``(m, n)``, r ``(n,)`` -> ``(m,)``.
 
     No padding copies of the plane: the grid only visits the
-    block-aligned column prefix of the FULL array (every accessed tile
+    block-aligned column prefix of the FULL array (every column tile
     stays in bounds, so nothing is re-materialized — a ``jnp.pad`` to a
     block multiple would silently copy the whole (m, n) matrix first)
     and the ragged tail (< ``block`` columns, if any) is reduced with a
-    plain jnp row pass and added. Rows that do not tile into ``block_m``
-    fall back to ``block_m=1``, which always divides. The grid sweeps
-    columns innermost, so each ``(block_m, 1)`` output tile accumulates
-    its rows' partials sequentially (race-free on TPU's sequential
-    grid)."""
+    plain jnp row pass and added. The row tile comes from
+    :func:`_row_block`, so every ``m`` compiles for the chip; a tile
+    over the VMEM budget raises. The grid sweeps columns innermost, so
+    each ``(block_m, 1)`` output tile accumulates its rows' partials
+    sequentially (race-free on TPU's sequential grid)."""
     m, n = x.shape
     rf = r.reshape(-1)
-    if m % block_m:
-        block_m = 1
+    bm = _row_block(m, block_m)
+    _check_tiles(bm, block, x.dtype, r.dtype)
     n0 = n - n % block
 
     def tail_sums(xt, rt):
@@ -106,12 +142,12 @@ def sqdist_rows(x, r, *, block_m: int = 8, block: int = 65536,
         return tail_sums(x, rf)
     out = pl.pallas_call(
         _sqdist_rows_kernel,
-        grid=(m // block_m, n0 // block),
+        grid=(pl.cdiv(m, bm), n0 // block),
         in_specs=[
-            pl.BlockSpec((block_m, block), lambda i, j: (i, j)),
+            pl.BlockSpec((bm, block), lambda i, j: (i, j)),
             pl.BlockSpec((1, block), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((block_m, 1), lambda i, j: (i, 0)),
+        out_specs=pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, 1), jnp.float32),
         interpret=interpret,
     )(x, rf.reshape(1, n))[:, 0]

@@ -71,7 +71,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_ref, h_scr, *,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, a, b, c, *, chunk: int = 64, interpret: bool = True):
+def ssd_scan(x, dt, a, b, c, *, chunk: int = 64, interpret: bool):
     """x: (BH, S, P); dt: (BH, S); a: (BH,); b, c: (BH, S, N).
 
     Returns (y (BH, S, P), h (BH, P, N)) — matching ``ref.ssd_scan_ref``.

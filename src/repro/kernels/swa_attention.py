@@ -62,7 +62,7 @@ def _swa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 @functools.partial(jax.jit, static_argnames=("window", "scale", "interpret"))
 def swa_attention(q, k, v, *, window: int, scale: float | None = None,
-                  interpret: bool = True):
+                  interpret: bool):
     """q, k, v: (B, S, d) with S % window == 0 and S >= window.
 
     Causal sliding-window attention (window == block size): each query

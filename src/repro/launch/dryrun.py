@@ -1,11 +1,14 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) program.
 
-The two lines above run before ANY other import (jax locks the device count
-on first init): the dry-run — and only the dry-run — sees 512 placeholder
-host devices so ``jax.make_mesh`` can build the production meshes.
+The lines above run before ANY other import (jax locks the platform and the
+device count on first init): the dry-run — and only the dry-run — sees 512
+placeholder host devices so ``jax.make_mesh`` can build the production
+meshes, and it stays on the CPU even where a TPU is attached, so a compile
+rehearsal never takes the chip.
 
 Usage:
     python -m repro.launch.dryrun --arch llama3-8b --shape train_4k --mesh single
@@ -28,7 +31,7 @@ import jax
 from repro.analysis import roofline as rl
 from repro.config import INPUT_SHAPES, get_arch
 from repro.configs import ASSIGNED_ARCHS
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND, make_production_mesh
 from repro.launch.specs import build_program
 from repro.telemetry import console_handler, get_logger
 
@@ -88,6 +91,7 @@ def run_one(arch: str, shape_name: str, mesh_kind: str, mode: str = "auto",
     mem = compiled.memory_analysis()
     report = rl.analyze(
         f"{prog.name}@{mesh_kind}", compiled, mesh.size,
+        PRODUCTION_DEVICE_KIND,
         model_flops=rl.model_flops_for(cfg, shape, prog.meta["mode"]))
 
     if calibrate:
@@ -101,9 +105,10 @@ def run_one(arch: str, shape_name: str, mesh_kind: str, mode: str = "auto",
         report.flops_per_chip = f1 + (L - 1) * max(f2 - f1, 0.0)
         report.bytes_per_chip = b1 + (L - 1) * max(b2 - b1, 0.0)
         report.wire_bytes_per_chip = w1 + (L - 1) * max(w2 - w1, 0.0)
-        report.compute_s = report.flops_per_chip / rl.PEAK_FLOPS_BF16
-        report.memory_s = report.bytes_per_chip / rl.HBM_BW
-        report.collective_s = report.wire_bytes_per_chip / rl.ICI_BW_PER_LINK
+        peaks = rl.chip_peaks(PRODUCTION_DEVICE_KIND)
+        report.compute_s = report.flops_per_chip / peaks.flops_bf16
+        report.memory_s = report.bytes_per_chip / peaks.hbm_bw
+        report.collective_s = report.wire_bytes_per_chip / peaks.ici_bw_per_link
         terms = {"compute": report.compute_s, "memory": report.memory_s,
                  "collective": report.collective_s}
         report.bottleneck = max(terms, key=terms.get)

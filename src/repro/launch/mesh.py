@@ -5,8 +5,7 @@ importing this module never touches jax device state. The dry-run entry
 point sets ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before
 any jax import; everything else sees the real (single) CPU device.
 
-Mesh construction goes through ``repro.compat`` so the ``axis_types``
-request degrades gracefully on jax releases without ``AxisType``.
+Mesh construction goes through ``repro.compat`` (Auto-typed axes).
 """
 from __future__ import annotations
 
@@ -24,7 +23,6 @@ def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     return make_mesh(shape, axes)
 
 
-# TPU v5e hardware constants (per chip) — roofline denominators.
-PEAK_FLOPS_BF16 = 197e12          # FLOP/s
-HBM_BW = 819e9                    # bytes/s
-ICI_BW_PER_LINK = 50e9            # bytes/s per link (~ per-device ring bw)
+# The chip the production meshes are built for (``jax.Device.device_kind``
+# of a TPU v5e); its peaks are ``repro.analysis.roofline.CHIP_PEAKS``.
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"

@@ -150,3 +150,13 @@ def test_roofline_model_flops():
     moe = get_arch("mixtral-8x22b")
     fm = model_flops_for(moe, INPUT_SHAPES["train_4k"], "train")
     assert fm < 6.0 * moe.param_count() * tokens
+
+
+def test_chip_peaks_keyed_by_device_kind():
+    """The roofline's peaks come from one table keyed by device_kind; a
+    kind missing from it is an error, not a v5e default."""
+    from repro.analysis.roofline import chip_peaks
+    v5e = chip_peaks("TPU v5 lite")
+    assert (v5e.flops_bf16, v5e.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(KeyError, match="TPU v4"):
+        chip_peaks("TPU v4")

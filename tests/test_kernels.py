@@ -90,6 +90,17 @@ def test_sqdist_rows_property(m, n, seed):
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("block_m,block,match", [
+    (8, 100, "multiple of 128"),          # not a lane multiple
+    (64, 65536, "VMEM"),                   # 2 x (64, 65536) f32 tiles
+])
+def test_sqdist_rows_rejects_tiles_the_chip_refuses(block_m, block, match):
+    X = jnp.ones((128, 2 * 65536))
+    r = jnp.zeros((2 * 65536,))
+    with pytest.raises(ValueError, match=match):
+        ops.sqdist_rows(X, r, block_m=block_m, block=block)
+
+
 # ---------------------------------------------------------------------------
 # rmsnorm
 # ---------------------------------------------------------------------------
@@ -225,7 +236,8 @@ def test_swa_attention_sweep(S, w, dtype):
     q = jax.random.normal(k0, (B, S, d), dtype)
     kk = jax.random.normal(jax.random.fold_in(k0, 1), (B, S, d), dtype)
     v = jax.random.normal(jax.random.fold_in(k0, 2), (B, S, d), dtype)
-    got = np.asarray(swa_attention(q, kk, v, window=w), np.float32)
+    got = np.asarray(swa_attention(q, kk, v, window=w, interpret=True),
+                     np.float32)
     want = np.asarray(ref.flash_attention_ref(q, kk, v, window=w),
                       np.float32)
     np.testing.assert_allclose(got, want, **_tol(dtype))
@@ -240,7 +252,7 @@ def test_swa_attention_matches_flash_kernel():
     q = jax.random.normal(k0, (B, S, d))
     kk = jax.random.normal(jax.random.fold_in(k0, 1), (B, S, d))
     v = jax.random.normal(jax.random.fold_in(k0, 2), (B, S, d))
-    banded = swa_attention(q, kk, v, window=w)
+    banded = swa_attention(q, kk, v, window=w, interpret=True)
     flash = ops.flash_attention(q, kk, v, window=w, block_q=32, block_k=32)
     np.testing.assert_allclose(np.asarray(banded), np.asarray(flash),
                                rtol=2e-4, atol=2e-5)

@@ -141,6 +141,24 @@ def test_constrain_rows_is_identity_without_a_fleet():
     assert shard.current_fleet() is None
 
 
+def test_kernel_distances_per_shard_match_dense():
+    """Under an active fleet the Pallas row kernel runs per shard
+    (shard_map over "fleet", interpret mode on the CPU) and agrees with
+    the dense rows."""
+    from repro.core.divergence import per_learner_sq_distance_flat
+    m, n = 8, 65536 + 3              # one kernel column block + a tail
+    fs = shard.fleet_sharding(m, 0)
+    k1, k2 = jax.random.split(jax.random.PRNGKey(3))
+    X = jax.device_put(jax.random.normal(k1, (m, n)), fs.row_sharding(2))
+    r = jax.random.normal(k2, (n,))
+    with shard.use_fleet(fs):
+        got = jax.jit(lambda X, r: per_learner_sq_distance_flat(
+            X, r, use_kernel=True))(X, r)
+    want = per_learner_sq_distance_flat(X, r, use_kernel=False)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5)
+
+
 # ---------------------------------------------------------------------------
 # the acceptance criterion: sharded == flat for every preset
 # ---------------------------------------------------------------------------
