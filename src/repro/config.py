@@ -661,16 +661,16 @@ class TelemetryConfig:
     ``per_link`` adds the per-link byte vector to every round record
     (L integers per round — sizeable for large fleets, hence opt-in).
     ``profile`` adds wall-clock + recompile accounting per chunk
-    (``perf_counter`` around a blocked dispatch). ``jax_profiler`` wraps
-    each chunk in a ``jax.profiler`` step annotation so chunks show up
-    named in a profiler trace (no-op unless a trace is active).
+    (``perf_counter`` around a blocked dispatch; whether the chunk
+    compiled comes from ``repro.telemetry.trace.trace_counts``). Named
+    spans and scopes show up in any ``jax.profiler`` trace without a
+    config (``repro.telemetry.trace``).
     """
     path: Optional[str] = None    # JSONL sink; None = ring buffer only
     append: bool = False          # append to path (checkpoint resume)
     ring: int = 1024              # in-memory ring capacity (records)
     per_link: bool = False        # per-link bytes on every round record
     profile: bool = False         # wall-clock + recompile spans per chunk
-    jax_profiler: bool = False    # jax.profiler step annotations
 
     def __post_init__(self):
         if self.ring < 1:

@@ -24,7 +24,8 @@ tree-layout aggregates accumulate in the same promoted dtype
 abstract outputs on a mixed f32+bf16 template.
 
 Offsets and shapes are plain Python/numpy metadata, so ``ravel``/
-``unravel`` trace to pure reshape+concatenate (no arithmetic) and work
+``unravel`` trace to pure reshape+concatenate (no arithmetic), under the
+named scopes ``repro.plane.ravel``/``repro.plane.unravel``, and work
 under ``jit``, ``vmap`` (the hierarchy's per-cluster path) and
 ``lax.scan`` — an unchanged row survives a ravel/unravel round trip
 bit-for-bit, which keeps non-participants bitwise across flat commits.
@@ -55,31 +56,35 @@ class FleetAdapter(NamedTuple):
     # ---- fleet (m, ...) <-> (m, P) ----------------------------------
     def ravel(self, stacked) -> jnp.ndarray:
         """Stacked (m, ...) pytree -> one (m, P) plane."""
-        leaves = self.treedef.flatten_up_to(stacked)
-        return jnp.concatenate(
-            [x.reshape(x.shape[0], -1).astype(self.plane_dtype)
-             for x in leaves], axis=1)
+        with jax.named_scope("repro.plane.ravel"):
+            leaves = self.treedef.flatten_up_to(stacked)
+            return jnp.concatenate(
+                [x.reshape(x.shape[0], -1).astype(self.plane_dtype)
+                 for x in leaves], axis=1)
 
     def unravel(self, X: jnp.ndarray):
         """(m, P) plane -> stacked (m, ...) pytree with the leaf dtypes."""
         m = X.shape[0]
-        leaves = [
-            X[:, o:o + s].reshape((m,) + shp).astype(dt)
-            for o, s, shp, dt in zip(self.offsets, self.sizes,
-                                     self.shapes, self.dtypes)]
+        with jax.named_scope("repro.plane.unravel"):
+            leaves = [
+                X[:, o:o + s].reshape((m,) + shp).astype(dt)
+                for o, s, shp, dt in zip(self.offsets, self.sizes,
+                                         self.shapes, self.dtypes)]
         return jax.tree.unflatten(self.treedef, leaves)
 
     # ---- single model (...) <-> (P,) --------------------------------
     def ravel_model(self, model) -> jnp.ndarray:
-        leaves = self.treedef.flatten_up_to(model)
-        return jnp.concatenate(
-            [x.reshape(-1).astype(self.plane_dtype) for x in leaves])
+        with jax.named_scope("repro.plane.ravel"):
+            leaves = self.treedef.flatten_up_to(model)
+            return jnp.concatenate(
+                [x.reshape(-1).astype(self.plane_dtype) for x in leaves])
 
     def unravel_model(self, x: jnp.ndarray):
-        leaves = [
-            x[o:o + s].reshape(shp).astype(dt)
-            for o, s, shp, dt in zip(self.offsets, self.sizes,
-                                     self.shapes, self.dtypes)]
+        with jax.named_scope("repro.plane.unravel"):
+            leaves = [
+                x[o:o + s].reshape(shp).astype(dt)
+                for o, s, shp, dt in zip(self.offsets, self.sizes,
+                                         self.shapes, self.dtypes)]
         return jax.tree.unflatten(self.treedef, leaves)
 
 

@@ -38,7 +38,6 @@ aggregator-uplink rows priced at the inter tier's payload size.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 import time
 from typing import Any, Callable, NamedTuple, Optional
@@ -65,6 +64,7 @@ from repro.network import faults as net_faults
 from repro.network import topology as net_topology
 from repro.optim import make_optimizer
 from repro.telemetry import sink
+from repro.telemetry.trace import ChunkProfiler, span
 
 
 class ProtocolMetrics(NamedTuple):
@@ -282,7 +282,6 @@ class DecentralizedLearner:
         self._profiler = None
         if telemetry is not None:
             from repro.telemetry.recorder import RoundRecorder
-            from repro.telemetry.trace import ChunkProfiler
             self._profiler = ChunkProfiler()
             self.recorder = RoundRecorder(
                 telemetry, m=m, num_links=self.num_links,
@@ -363,7 +362,8 @@ class DecentralizedLearner:
                             sync_state.extra, restart, m))
             # availability means REACHABILITY: every learner still takes its
             # local SGD step; unavailable ones just cannot communicate
-            upd, opt_upd, losses = update(params, opt_state, batches)
+            with jax.named_scope("repro.local_update"):
+                upd, opt_upd, losses = update(params, opt_state, batches)
             if faults is not None:
                 # a learner mid-outage is STATELESS, not just unreachable:
                 # its training freezes (the update is discarded) and it
@@ -605,7 +605,7 @@ class DecentralizedLearner:
             # batch never materializes whole on any single device
             batches = shard.put_fleet(self.fleet, batches, axis=0)
         if self.recorder is not None:
-            return self._run_observed(self._step, self._fold_step_t,
+            return self._run_observed(self._step, "step", self._fold_step_t,
                                       batches, 1)
         self.params, self.opt_state, self.sync_state, metrics = self._step(
             self.params, self.opt_state, self.sync_state, batches)
@@ -629,50 +629,57 @@ class DecentralizedLearner:
         chunk size (plus at most one remainder) as ``train.loop`` does.
         """
         n = int(jax.tree.leaves(batches)[0].shape[0])
-        if self.fleet is not None:   # (n, m, B, ...): the learner axis is 1
-            batches = shard.put_fleet(self.fleet, batches, axis=1)
-        if self.recorder is not None:
-            return self._run_observed(self._chunk, self._fold_chunk_t,
-                                      batches, n)
-        self.params, self.opt_state, self.sync_state, metrics = self._chunk(
-            self.params, self.opt_state, self.sync_state, batches)
-        self._accumulate(jax.device_get(self._fold_chunk(metrics)), n)
-        return metrics
+        r = self.rounds
+        with span("repro.engine.run_chunk", round=r):
+            if self.fleet is not None:   # (n, m, B, ...): learner axis 1
+                batches = shard.put_fleet(self.fleet, batches, axis=1)
+            if self.recorder is not None:
+                return self._run_observed(self._chunk, "chunk",
+                                          self._fold_chunk_t, batches, n)
+            with span("repro.engine.dispatch", round=r):
+                (self.params, self.opt_state, self.sync_state,
+                 metrics) = self._chunk(self.params, self.opt_state,
+                                        self.sync_state, batches)
+                folded = self._fold_chunk(metrics)
+            with span("repro.engine.fetch", round=r):
+                host = jax.device_get(folded)
+            with span("repro.engine.accumulate", round=r):
+                self._accumulate(host, n)
+            return metrics
 
     # ------------------------------------------------------------------
-    def _run_observed(self, compute, fold, batches, n: int):
+    def _run_observed(self, compute, program: str, fold, batches, n: int):
         """The telemetered dual of ``step``/``run_chunk``: identical
         device programs (the round/chunk computation is byte-for-byte the
         untelemetered one — only the fold carries the extra ``per_round``
         reductions), ONE ``device_get`` of (fold output, trigger-carried
-        state snapshot), then host-side record materialization."""
-        cfg = self.telemetry
-        profiling = cfg.profile
-        compiled = self._profiler.begin(n) if profiling else None
+        state snapshot), then host-side record materialization.
+        ``program`` is ``compute``'s name in ``trace_counts``."""
+        profiling = self.telemetry.profile
+        r = self.rounds
         base = self.counters_snapshot()
-        t0 = time.perf_counter() if profiling else None
-        ctx = (self._step_annotation() if cfg.jax_profiler
-               else contextlib.nullcontext())
-        with ctx:
+        if profiling:
+            self._profiler.begin(program)
+            t0 = time.perf_counter()
+        with span("repro.engine.dispatch", round=r):
             self.params, self.opt_state, self.sync_state, metrics = compute(
                 self.params, self.opt_state, self.sync_state, batches)
-            # one transfer, and it blocks on the whole round program —
-            # the wall-clock below covers execution, not async dispatch
-            host, extra = jax.device_get(
-                (fold(metrics), self._state_extra()))
-        wall = time.perf_counter() - t0 if profiling else None
+            folded = fold(metrics), self._state_extra()
+        # one transfer, and it blocks on the whole round program — the
+        # wall-clock below covers execution, not async dispatch
+        with span("repro.engine.fetch", round=r):
+            host, extra = jax.device_get(folded)
+        wall = compiled = recompiles = None
         if profiling:
-            self._profiler.observe(n, wall)
-        self._accumulate(host, n)
-        self.recorder.observe(
-            host["per_round"], base, extra, n, wall_s=wall,
-            compiled=compiled,
-            recompiles=self._profiler.recompiles if profiling else None)
+            wall = time.perf_counter() - t0
+            compiled = self._profiler.end()
+            recompiles = self._profiler.recompiles
+        with span("repro.engine.accumulate", round=r):
+            self._accumulate(host, n)
+            self.recorder.observe(
+                host["per_round"], base, extra, n, wall_s=wall,
+                compiled=compiled, recompiles=recompiles)
         return metrics
-
-    def _step_annotation(self):
-        from repro.telemetry.trace import step_annotation
-        return step_annotation("repro_round", self.rounds)
 
     def _state_extra(self):
         """The trigger-declared carried state (e.g. staleness ages) as a

@@ -316,9 +316,13 @@ def _compiled_round(spec: ProtocolSpec):
                            stages.zeros_i32(m), stages.zeros_i32(m))
 
         def pipeline(sctx, hot, nhot, rng):
-            cout = coh.fn(sctx, hot, nhot, rng)
-            out = com.fn(sctx, cout, agg.fn(sctx, cout), hot, nhot)
-            out = out._replace(extra=trig.commit_extra(sctx, cout.mask))
+            with jax.named_scope("repro.sync.cohort"):
+                cout = coh.fn(sctx, hot, nhot, rng)
+            with jax.named_scope("repro.sync.aggregate"):
+                agg_out = agg.fn(sctx, cout)
+            with jax.named_scope("repro.sync.commit"):
+                out = com.fn(sctx, cout, agg_out, hot, nhot)
+                out = out._replace(extra=trig.commit_extra(sctx, cout.mask))
             if adapter is not None:
                 out = out._replace(
                     params=adapter.unravel(shard.constrain_rows(out.params)),
@@ -333,7 +337,8 @@ def _compiled_round(spec: ProtocolSpec):
                     ref_flat=adapter.ravel_model(state.ref))
             if trig.condition is None:
                 return pipeline(sctx, reach, None, rng)
-            cond = trig.condition(sctx)
+            with jax.named_scope("repro.sync.condition"):
+                cond = trig.condition(sctx)
             hot, nhot = cond[0], cond[1]
             if len(cond) > 2:     # condition extras -> downstream stages
                 sctx = sctx._replace(cond_aux=cond[2])
@@ -341,11 +346,16 @@ def _compiled_round(spec: ProtocolSpec):
                 nhot > 0, lambda r: pipeline(sctx, hot, nhot, r),
                 skip_out, rng)
 
-        gate = trig.gate(ctx)
-        if gate is False:      # statically-never trigger (nosync): no cond
-            out = skip_out(state.rng)
-        else:
-            out = jax.lax.cond(gate, sync, skip_out, state.rng)
+        # the gate, its cond and the stages carry stable named scopes,
+        # which a profile shows as each device op's name path; the scope
+        # holds the cond itself, so the copies XLA puts at its boundary
+        # count as the sync's too
+        with jax.named_scope("repro.sync"):
+            gate = trig.gate(ctx)
+            if gate is False:  # statically-never trigger (nosync): no cond
+                out = skip_out(state.rng)
+            else:
+                out = jax.lax.cond(gate, sync, skip_out, state.rng)
         new_state = state._replace(ref=out.ref, v=out.v, rng=out.rng,
                                    step=t, extra=out.extra)
         return StageResult(out.params, new_state, out.rec, out.xfers,

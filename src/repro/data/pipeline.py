@@ -20,6 +20,8 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.telemetry.trace import span
+
 
 class LearnerStreams:
     def __init__(self, source, m: int, batch: int = 10, seed: int = 0,
@@ -96,14 +98,15 @@ class LearnerStreams:
         the per-round host path (the concept may change mid-chunk)."""
         if n < 1:
             raise ValueError(f"chunk length must be >= 1, got {n}")
-        if on_round is None and self.fused_chunks:
-            self._key, batches = self._chunk_sampler(n)(
-                self._key, self.source.concept())
-            self._round += n
-            return batches
-        rounds = []
-        for i in range(n):
-            if on_round is not None:
-                on_round(i)
-            rounds.append(self.next())
-        return jax.tree.map(lambda *xs: jnp.stack(xs), *rounds)
+        with span("repro.streams.next_chunk", round=self._round):
+            if on_round is None and self.fused_chunks:
+                self._key, batches = self._chunk_sampler(n)(
+                    self._key, self.source.concept())
+                self._round += n
+                return batches
+            rounds = []
+            for i in range(n):
+                if on_round is not None:
+                    on_round(i)
+                rounds.append(self.next())
+            return jax.tree.map(lambda *xs: jnp.stack(xs), *rounds)

@@ -11,9 +11,10 @@ into):
    ``DecentralizedLearner(telemetry=...)`` /
    ``run_protocol_training(telemetry=...)`` /
    ``benchmarks/run.py --telemetry``.
-2. **Tracing & profiling** (``trace``/``costs``): blocked wall-clock
-   spans, per-chunk-length recompile accounting, optional
-   ``jax.profiler`` integration, and static per-stage cost attribution
+2. **Tracing & profiling** (``trace``/``costs``): named host spans on
+   the driver path and named device scopes in the programs (both show
+   up in any ``jax.profiler`` trace), per-program trace/compile counts,
+   blocked wall-clock timing, and static per-stage cost attribution
    (jaxpr FLOPs × observed trigger fires).
 3. **Observatory** (``observatory``, ``python -m repro.telemetry``):
    summarize/tail a recorded stream — comm-vs-loss frontier, sync
@@ -30,12 +31,12 @@ from repro.telemetry.sink import (  # noqa: F401
     jsonl_handler,
 )
 from repro.telemetry.trace import (  # noqa: F401
-    ChunkProfiler, profiler_trace, span, step_annotation, timed,
+    ChunkProfiler, span, timed, trace_counts,
 )
 
 __all__ = [
     "SCHEMA_VERSION", "RoundRecord", "chunk_record", "meta_record",
     "validate_record", "RoundRecorder", "TelemetrySink", "TelemetryLogger",
     "get_logger", "console_handler", "jsonl_handler", "timed", "span",
-    "profiler_trace", "step_annotation", "ChunkProfiler",
+    "trace_counts", "ChunkProfiler",
 ]

@@ -24,6 +24,7 @@ from repro.config import (
 from repro.core import operators as ops
 from repro.core.protocol import DecentralizedLearner
 from repro.data.pipeline import LearnerStreams
+from repro.telemetry.trace import span
 
 # Default scan length: long enough that per-chunk dispatch is noise, short
 # enough that the stacked (n, m, B, ...) batch chunk stays small on CPU.
@@ -124,37 +125,41 @@ def run_protocol_training(
             if source.maybe_drift():
                 traj.drift_rounds.append(t + i)
 
-        base_loss = dl.cumulative_loss
-        base_totals = dict(dl.comm_totals)
-        base_net_time = dl.network_time
-        base_ledger = int(dl.link_bytes_totals.sum())
-        metrics = dl.run_chunk(streams.next_chunk(
-            n, on_round=on_round if drifting else None))
+        with span("repro.loop.chunk", round=t):
+            base_loss = dl.cumulative_loss
+            base_totals = dict(dl.comm_totals)
+            base_net_time = dl.network_time
+            base_ledger = int(dl.link_bytes_totals.sum())
+            metrics = dl.run_chunk(streams.next_chunk(
+                n, on_round=on_round if drifting else None))
 
-        # reconstruct the per-round cumulative trajectory from the chunk
-        loss_cum = base_loss + np.cumsum(
-            np.asarray(jnp.sum(metrics.loss_per_learner, axis=1), np.float64))
-        comm_cum = {k: base_totals[k] + np.cumsum(
-            np.asarray(getattr(metrics.comm, k), np.int64))
-            for k in ops.CommRecord._fields}
-        # under a hierarchy the tiers move different payload sizes, so the
-        # byte curve comes from the per-round ledger (link counts priced
-        # host-side at each link's payload size), not the scalar counts
-        ledger_cum = base_ledger + np.cumsum(
-            dl.price_link_counts(
-                np.asarray(metrics.link_counts, np.int64)).sum(axis=1))
-        net_cum = base_net_time + np.cumsum(
-            np.asarray(metrics.net_time, np.float64))
-        for i in range(n):
-            g = t + i
-            if (g + 1) % record_every == 0 or g == rounds - 1:
-                traj.rounds.append(g + 1)
-                traj.cumulative_loss.append(float(loss_cum[i]))
-                traj.cumulative_bytes.append(
-                    int(ledger_cum[i]) if dl.tiers is not None
-                    else dl.comm_bytes_of(
-                        {k: int(v[i]) for k, v in comm_cum.items()}))
-                traj.syncs.append(int(comm_cum["syncs"][i]))
-                traj.network_time.append(float(net_cum[i]))
+            # reconstruct the per-round cumulative trajectory from the
+            # chunk
+            with span("repro.loop.trajectory", round=t):
+                loss_cum = base_loss + np.cumsum(np.asarray(
+                    jnp.sum(metrics.loss_per_learner, axis=1), np.float64))
+                comm_cum = {k: base_totals[k] + np.cumsum(
+                    np.asarray(getattr(metrics.comm, k), np.int64))
+                    for k in ops.CommRecord._fields}
+                # under a hierarchy the tiers move different payload
+                # sizes, so the byte curve comes from the per-round ledger
+                # (link counts priced host-side at each link's payload
+                # size), not the scalar counts
+                ledger_cum = base_ledger + np.cumsum(
+                    dl.price_link_counts(
+                        np.asarray(metrics.link_counts, np.int64)).sum(axis=1))
+                net_cum = base_net_time + np.cumsum(
+                    np.asarray(metrics.net_time, np.float64))
+                for i in range(n):
+                    g = t + i
+                    if (g + 1) % record_every == 0 or g == rounds - 1:
+                        traj.rounds.append(g + 1)
+                        traj.cumulative_loss.append(float(loss_cum[i]))
+                        traj.cumulative_bytes.append(
+                            int(ledger_cum[i]) if dl.tiers is not None
+                            else dl.comm_bytes_of(
+                                {k: int(v[i]) for k, v in comm_cum.items()}))
+                        traj.syncs.append(int(comm_cum["syncs"][i]))
+                        traj.network_time.append(float(net_cum[i]))
         t += n
     return dl, traj

@@ -1,6 +1,6 @@
 """The fleet telemetry plane (ISSUE 7).
 
-Four claims:
+Five claims:
 
 * **off is free** — a learner built WITHOUT telemetry reproduces the
   PR-2 goldens bitwise: the plane's existence changes nothing.
@@ -14,9 +14,13 @@ Four claims:
   ``save_protocol_state`` → ``load_counters`` → ``restore_counters``
   continues the stream as ONE continuous record (rounds contiguous
   across the resume boundary, cumulatives monotone).
+* **the program names its work** — the driver's host spans nest per
+  chunk and share its round in a profiler trace, the chunk program
+  carries the device scopes, and ``trace_counts`` sees each compile.
 """
 import json
 import os
+import re
 import sys
 
 import jax
@@ -38,6 +42,7 @@ from repro.telemetry.observatory import frontier, load_run, summarize
 from repro.telemetry.record import (
     SCHEMA_VERSION, RoundRecord, validate_record,
 )
+from repro.telemetry.trace import trace_counts
 
 from golden_pr2_capture import CASES, M, ROUNDS, params_sha256
 from hypothesis_compat import given, settings, st
@@ -161,6 +166,107 @@ def test_telemetry_no_extra_device_fetches(tmp_path, monkeypatch):
     dl.run_chunk(streams.next_chunk(8))
     dl.recorder.close()
     assert len(calls) == 1
+
+
+# ---------------------------------------------------------------------------
+# names in the program: host spans, device scopes, trace/compile counts
+# ---------------------------------------------------------------------------
+
+# (span, its parent) on the driver path, per chunk
+SPAN_PARENTS = {
+    "repro.loop.chunk": None,
+    "repro.streams.next_chunk": "repro.loop.chunk",
+    "repro.engine.run_chunk": "repro.loop.chunk",
+    "repro.engine.dispatch": "repro.engine.run_chunk",
+    "repro.engine.fetch": "repro.engine.run_chunk",
+    "repro.engine.accumulate": "repro.engine.run_chunk",
+    "repro.loop.trajectory": "repro.loop.chunk",
+}
+
+
+def test_driver_spans_nest_and_share_round(tmp_path):
+    """Two chunks of a tiny flat fleet under ``jax.profiler`` on the CPU,
+    reduced by the benchmark's own trace reader: every span of the driver
+    path appears once per chunk, inside its parent, and the spans of one
+    chunk carry that chunk's first round."""
+    from repro.train.loop import run_protocol_training
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from perfbench import trace as ptrace
+
+    cfg = get_arch("drift_mlp", smoke=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation(ptrace.WINDOW_SPAN):
+            run_protocol_training(
+                lambda p, b: cnn_loss(cfg, p, b),
+                lambda k: init_cnn_params(cfg, k),
+                GraphicalModelStream(seed=0, drift_prob=0.0), m=4, rounds=6,
+                protocol=ProtocolConfig(kind="dynamic", b=1, delta=0.5,
+                                        layout="flat"),
+                train=TrainConfig(optimizer="sgd", learning_rate=0.05),
+                chunk_size=3)
+    finally:
+        jax.profiler.stop_trace()
+    host = [h for h in ptrace.reduce_dir(str(tmp_path), chips=1).host
+            if h.name in SPAN_PARENTS]
+    by_round = {}
+    for h in host:
+        by_round.setdefault(h.stats["round"], []).append(h)
+    assert sorted(by_round) == [0, 3]
+    for spans in by_round.values():
+        named = {h.name: h for h in spans}
+        assert sorted(h.name for h in spans) == sorted(SPAN_PARENTS)
+        for name, parent in SPAN_PARENTS.items():
+            if parent is not None:
+                inner, outer = named[name], named[parent]
+                assert outer.start <= inner.start <= inner.end <= outer.end
+
+
+def _lowered_scopes(layout):
+    """The ``repro.*`` components of the name paths in the lowered
+    chunk program of a dynamic fleet."""
+    cfg = get_arch("drift_mlp", smoke=True)
+    streams = LearnerStreams(GraphicalModelStream(seed=0, drift_prob=0.0),
+                             4, batch=10, seed=0)
+    dl = DecentralizedLearner(
+        lambda p, b: cnn_loss(cfg, p, b), lambda k: init_cnn_params(cfg, k),
+        4, ProtocolConfig(kind="dynamic", b=1, delta=0.5, layout=layout),
+        TrainConfig(optimizer="sgd", learning_rate=0.05))
+    text = dl._chunk.lower(dl.params, dl.opt_state, dl.sync_state,
+                           streams.next_chunk(2)).as_text(debug_info=True)
+    return {c for path in re.findall(r'loc\("([^"]*)"', text)
+            for c in path.split("/") if c.startswith("repro.")}
+
+
+@pytest.mark.parametrize("layout", ["flat", "tree"])
+def test_chunk_program_carries_named_scopes(layout):
+    stages = {"repro.local_update", "repro.sync", "repro.sync.condition",
+              "repro.sync.cohort", "repro.sync.aggregate",
+              "repro.sync.commit"}
+    plane = {"repro.plane.ravel", "repro.plane.unravel"}
+    want = stages | plane if layout == "flat" else stages
+    assert _lowered_scopes(layout) == want
+
+
+def test_trace_counts_see_each_compile_of_the_chunk():
+    """A second chunk of the same length is served from jit's cache and
+    counts nothing; a new length traces and compiles the chunk once."""
+    proto, network = CASES["dynamic_ideal"]
+    dl, streams = _learner(proto, network)
+
+    def chunk_counts():
+        return trace_counts().get("chunk", {"traces": 0, "compiles": 0})
+
+    dl.run_chunk(streams.next_chunk(2))
+    before = chunk_counts()
+    dl.run_chunk(streams.next_chunk(2))
+    assert chunk_counts() == before
+    dl.run_chunk(streams.next_chunk(3))
+    after = chunk_counts()
+    assert after == {"traces": before["traces"] + 1,
+                     "compiles": before["compiles"] + 1}
 
 
 # ---------------------------------------------------------------------------
