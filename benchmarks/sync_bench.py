@@ -4,8 +4,8 @@ Times ONE staged round of the dynamic-averaging protocol — the paper's
 hot loop: divergence monitoring over every learner plus the balancing
 augmentation — on the paper's 1,199,882-parameter MNIST CNN, for
 ``layout="tree"`` (per-leaf pytree expressions, the pre-flat engine) and
-``layout="flat"`` (one (m, P) matrix through the stages,
-``repro.core.flatten``), from IDENTICAL state.
+``layout="flat"`` (the blocked fleet plane through the stages,
+``repro.core.sync.stages``), from IDENTICAL state.
 
 The fleet is constructed so the balancing loop does real work and the
 augmentation count is exact: ``v = m/8`` violators drift a distance
@@ -222,9 +222,10 @@ def check(rows) -> str:
                   for r in flat.values())
           # balancing cost per augmentation step independent of m: the
           # marginal iteration must cost less than 1/m of the round's
-          # fixed O(m*P) work (ravel + dists + commit + unravel are ~7
-          # full-plane passes) — an O(m*P) iteration, like the tree
-          # layout's full re-aggregation, would cost ~1/7 of the round.
+          # fixed O(m*P) work (dists, the running sum, the mean and the
+          # commit are ~5 full-plane passes) — an O(m*P) iteration, like
+          # the tree layout's full re-aggregation, would cost ~1/5 of
+          # the round.
           # The probe must also come out POSITIVE: a negative difference
           # of the two timings means noise swamped the signal and the
           # claim was not actually measured — fail loudly, don't pass

@@ -13,16 +13,17 @@ compile: they say the program ran, they are not rates to compare.
 One chip: m=128 learners, B=10, ``layout="flat"``, two chunks of 16
 rounds, the local condition checked every round (b=1) against Δ=0.05, so
 some checks end in partial syncs and some in full ones. Checks: finite
-loss and syncs > 0; the ``sqdist_rows`` kernel agrees with the dense jnp
-rows on the live fleet plane; the compiled chunk contains the kernel
-(``tpu_custom_call``); the same fleet under ``layout="tree"``, with
+loss and syncs > 0; the blocked fleet plane's distances agree with the
+tree layout's on the live fleet; the compiled chunk copies, slices,
+pads or concatenates nothing the size of the fleet inside its loops; the
+same fleet under ``layout="tree"``, with
 b=10 and a Δ every check exceeds (full syncs only), gives bitwise-equal
 comm counters and per-link ledger, and parameters and loss within
 tolerance of flat.
 
 ``--chips 4``: m=256 under ``layout="sharded", shard_devices=4`` (finite
-loss, syncs > 0, the per-shard kernel against the dense rows on the live
-sharded plane), then against ``layout="flat"`` on one chip with the same
+loss, syncs > 0, the blocked distances against the tree layout's on the
+live sharded fleet), then against ``layout="flat"`` on one chip with the same
 seed, under full syncs.
 
 Each layout comparison runs both fleets at full f32 matmul precision (see
@@ -51,9 +52,10 @@ from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.config import (  # noqa: E402
     ProtocolConfig, TelemetryConfig, TrainConfig, get_arch,
 )
+from repro.analysis.hlo import loop_data_movement  # noqa: E402
 from repro.core import shard  # noqa: E402
-from repro.core.divergence import per_learner_sq_distance_flat  # noqa: E402
-from repro.core.flatten import fleet_adapter  # noqa: E402
+from repro.core.divergence import per_learner_sq_distance  # noqa: E402
+from repro.core.sync.spec import pin_rows  # noqa: E402
 from repro.data.pipeline import LearnerStreams  # noqa: E402
 from repro.data.synthetic import SyntheticMNIST  # noqa: E402
 from repro.models.cnn import cnn_loss, init_cnn_params  # noqa: E402
@@ -81,7 +83,7 @@ M_ONE_CHIP, M_FOUR_CHIPS = 128, 256
 # elementwise tolerance fails on the small elements a cancellation
 # leaves), and the cumulative loss by its relative difference.
 PARAM_RTOL, LOSS_RTOL = 1e-3, 1e-6
-KERNEL_RTOL = 1e-5
+DIST_RTOL = 1e-5
 
 
 def _require(ok: bool, what: str) -> None:
@@ -144,23 +146,28 @@ def compiled_chunk_text(dl, image_size: int) -> str:
                            batches).compile().as_text()
 
 
-def check_kernel_on_plane(dl) -> float:
-    """sqdist_rows vs the dense jnp rows on the live (m, P) plane. A
-    sharded learner's plane stays split over its devices and the kernel
-    runs per shard, as in the engine's round."""
-    adapter = fleet_adapter(dl.params)
-
-    def rows(params, ref, use_kernel):
-        X = shard.constrain_rows(adapter.ravel(params))
-        return per_learner_sq_distance_flat(X, adapter.ravel_model(ref),
-                                            use_kernel=use_kernel)
+def check_distances_on_blocks(dl) -> float:
+    """The blocked plane's (m,) distances to the reference against the
+    tree layout's on the live fleet. A sharded learner's blocks stay
+    split over its devices, as in the engine's round."""
+    def rows(params, ref, blocked):
+        return per_learner_sq_distance(
+            pin_rows(params) if blocked else params, ref)
 
     with (shard.use_fleet(dl.fleet) if dl.fleet is not None
           else contextlib.nullcontext()):
-        kern, dense = (np.asarray(jax.jit(rows, static_argnums=2)(
-            dl.params, dl.sync_state.ref, k)) for k in (True, False))
-    np.testing.assert_allclose(kern, dense, rtol=KERNEL_RTOL)
-    return float(np.max(np.abs(kern - dense) / np.abs(dense)))
+        blocked, tree = (np.asarray(jax.jit(rows, static_argnums=2)(
+            dl.params, dl.sync_state.ref, b)) for b in (True, False))
+    np.testing.assert_allclose(blocked, tree, rtol=DIST_RTOL)
+    return float(np.max(np.abs(blocked - tree) / np.abs(tree)))
+
+
+def fleet_sized_moves(dl, hlo: str):
+    """Data-movement ops inside the compiled chunk's loops (copies,
+    slices, pads, concatenations) at least the size of the fleet's
+    largest leaf: each would be a fleet-sized relayout every round."""
+    big = max(x.nbytes for x in jax.tree.leaves(dl.params))
+    return [op for op in loop_data_movement(hlo) if op[3] >= big]
 
 
 def check_same_fleet(a, b, what: str) -> None:
@@ -220,13 +227,13 @@ def one_chip(m: int = M_ONE_CHIP, cfg=None) -> None:
     flat = timed_fleet("flat", m, "flat", cfg=cfg)
     _require(np.isfinite(flat.cumulative_loss), "flat loss not finite")
     _require(flat.comm_totals["syncs"] > 0, "no sync fired")
-    rel = check_kernel_on_plane(flat)
-    hlo = compiled_chunk_text(flat, cfg.input_shape[0])
-    _require("tpu_custom_call" in hlo,
-             "compiled chunk has no Pallas kernel (tpu_custom_call)")
-    print(f"[smoke flat] sqdist_rows vs dense rows: max rel err {rel!r}; "
-          f"tpu_custom_call in compiled chunk; peak_bytes_in_use="
-          f"{peak_bytes()}")
+    rel = check_distances_on_blocks(flat)
+    moves = fleet_sized_moves(flat, compiled_chunk_text(flat,
+                                                        cfg.input_shape[0]))
+    _require(not moves, f"compiled chunk relays out the fleet: {moves}")
+    print(f"[smoke flat] blocked vs tree distances: max rel err {rel!r}; "
+          f"no fleet-sized copy in the compiled chunk's loops; "
+          f"peak_bytes_in_use={peak_bytes()}")
     del flat
     check_layouts(m, cfg, "tree")
 
@@ -235,9 +242,9 @@ def four_chips(m: int = M_FOUR_CHIPS, n: int = 4, cfg=None) -> None:
     sharded = timed_fleet("sharded", m, "sharded", n, cfg)
     _require(np.isfinite(sharded.cumulative_loss), "sharded loss not finite")
     _require(sharded.comm_totals["syncs"] > 0, "no sync fired")
-    rel = check_kernel_on_plane(sharded)
-    print(f"[smoke sharded] per-shard sqdist_rows vs dense rows on the "
-          f"sharded plane: max rel err {rel!r}")
+    rel = check_distances_on_blocks(sharded)
+    print(f"[smoke sharded] blocked vs tree distances on the sharded "
+          f"fleet: max rel err {rel!r}")
     del sharded
     check_layouts(m, cfg, "sharded", n)
 

@@ -19,7 +19,7 @@ branches, while loops and closed calls — flagging:
   carry never stabilizes.
 * **dynamic-shape** — an equation output whose shape is not fully
   static (polymorphic dims); the fleet plane is a statically-shaped
-  (m, P) program by construction.
+  program by construction.
 * **int32-accumulator** — a narrow-int scan carry that grows by a
   data-dependent amount each iteration with no reset, i.e. one that can
   wrap silently. The engine's legitimate int32 counters pass: literal

@@ -18,8 +18,9 @@ preset) traces in seconds and proves, for each combination:
   ``manages_v`` (scalar int32 + scalar bool full flag), ``aux`` keys
   match the declaration.
 * **aggregate** — the output matches its declared kind: ``"model"`` is a
-  single-model pytree (tree layout) / a (P,) plane row (flat layout),
-  ``"fleet"`` an (m, ...) stacked pytree / the (m, P) plane.
+  single-model pytree, ``"fleet"`` an (m, ...) stacked pytree, in the
+  leaf dtypes on every layout (the plane layouts' blocked plane is the
+  stacked leaves and its row the model pytree).
 * **commit + round** — the committed configuration and reference keep
   the input shapes AND dtypes bitwise (no promotion drift past the
   boundary), ``v``/``step``/``CommRecord``/``xfers``/``link_msgs`` are
@@ -41,13 +42,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis.report import Finding
-from repro.core import flatten
 from repro.core.sync import registry, stages
 from repro.core.sync.registry import (
     AGGREGATES, COHORTS, COMMITS, PROTOCOLS, TRIGGERS, StageCtx, SyncState,
 )
 from repro.core.sync.spec import (
-    GLOBAL_PARAMS, LAYOUTS, PLANE_LAYOUTS, ProtocolSpec,
+    GLOBAL_PARAMS, LAYOUTS, PLANE_LAYOUTS, ProtocolSpec, pin_rows,
 )
 
 __all__ = [
@@ -141,8 +141,7 @@ def _trace_slots(spec: ProtocolSpec, template, *, weighted: bool,
                  with_active: bool) -> Dict[str, Any]:
     """Abstract-evaluate every slot of ``spec`` on ``template``, mirroring
     ``_compiled_round``'s exact ctx wiring, and return the per-slot
-    ``ShapeDtypeStruct`` trees (plus the plane views under the flat
-    layout)."""
+    ``ShapeDtypeStruct`` trees."""
     trig, coh, agg, com = spec.stage_records()
     p = spec.resolved_params()
     flat_layout = p["layout"] in PLANE_LAYOUTS
@@ -156,17 +155,13 @@ def _trace_slots(spec: ProtocolSpec, template, *, weighted: bool,
         out = {}
         t = st.step + 1
         reach = stages.cohort_all(m, active)
-        adapter = flatten.fleet_adapter(stacked) if flat_layout else None
         ctx = StageCtx(params=p, stacked=stacked, state=st, weights=weights,
                        active=active, adjacency=adjacency, m=m, t=t,
-                       reach=reach, adapter=adapter)
+                       reach=reach)
         g = trig.gate(ctx)
         out["gate"] = jnp.asarray(g) if isinstance(g, bool) else g
-        if adapter is not None:
-            ctx = ctx._replace(flat=adapter.ravel(stacked),
-                               ref_flat=adapter.ravel_model(st.ref))
-            out["plane"] = ctx.flat
-            out["ref_plane"] = ctx.ref_flat
+        if flat_layout:
+            ctx = ctx._replace(stacked=pin_rows(stacked), plane=True)
         hot, nhot = reach, None
         if trig.condition is not None:
             cond = trig.condition(ctx)
@@ -220,9 +215,6 @@ def check_spec(spec: ProtocolSpec, template=None, *, weighted: bool = False,
     except Exception as e:  # noqa: BLE001 — any trace failure is a finding
         return [Finding("contracts", "trace-error", label, _fmt(e))]
 
-    flat_layout = spec.param("layout") in PLANE_LAYOUTS
-    plane_sig = _sig(tr.get("plane"))
-    ref_plane_sig = _sig(tr.get("ref_plane"))
     ref_sig = _sig_tree(jax.tree.map(lambda s: _sds(s.shape[1:], s.dtype),
                                      template))
     tmpl_sig = _sig_tree(template)
@@ -311,10 +303,7 @@ def check_spec(spec: ProtocolSpec, template=None, *, weighted: bool = False,
     # ---- aggregate ----------------------------------------------------
     kind = agg.contract.out if agg.contract else "model"
     agg_sig = _sig_tree(tr["aggregate"])
-    if kind == "model":
-        want = ref_plane_sig if flat_layout else ref_sig
-    else:  # "fleet"
-        want = plane_sig if flat_layout else tmpl_sig
+    want = ref_sig if kind == "model" else tmpl_sig
     if agg_sig != want:
         bad("aggregate-out", f"aggregate:{agg.name}",
             f"declared out={kind!r}: abstract output {agg_sig} != "
@@ -322,16 +311,14 @@ def check_spec(spec: ProtocolSpec, template=None, *, weighted: bool = False,
 
     # ---- commit -------------------------------------------------------
     sout = tr["commit"]
-    want_params = plane_sig if flat_layout else tmpl_sig
-    want_ref = ref_plane_sig if flat_layout else ref_sig
-    if _sig_tree(sout.params) != want_params:
+    if _sig_tree(sout.params) != tmpl_sig:
         bad("commit-params", f"commit:{com.name}",
             f"committed configuration {_sig_tree(sout.params)} != input "
-            f"{want_params} — shapes and dtypes must be preserved bitwise")
-    if _sig_tree(sout.ref) != want_ref:
+            f"{tmpl_sig} — shapes and dtypes must be preserved bitwise")
+    if _sig_tree(sout.ref) != ref_sig:
         bad("commit-ref", f"commit:{com.name}",
             f"committed reference {_sig_tree(sout.ref)} != input "
-            f"{want_ref}")
+            f"{ref_sig}")
     if not _is_scalar(sout.v, jnp.int32):
         bad("counter-dtype", f"commit:{com.name}",
             f"carried v must be scalar int32, got {_sig(sout.v)}")

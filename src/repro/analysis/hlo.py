@@ -1,4 +1,5 @@
-"""Collective-byte accounting from post-partition HLO text.
+"""Collective-byte accounting and data-movement checks from compiled HLO
+text.
 
 ``cost_analysis()`` has no collective-byte entry, so we parse the compiled
 module text. The compiled module is the per-device (SPMD-partitioned)
@@ -13,6 +14,11 @@ factors:
     collective-permute B
 
 (n = collective group size parsed from ``replica_groups``).
+
+``loop_data_movement`` lists the copies, concatenations, slices, pads and
+dynamic-update-slices a compiled program runs inside its loops, alone or
+as fusions of nothing else: ops that do no arithmetic, only move data,
+once per iteration.
 """
 from __future__ import annotations
 
@@ -165,3 +171,77 @@ def count_op(hlo_text: str, opname: str) -> int:
         if m and m.group(3) == opname:
             n += 1
     return n
+
+
+# a computation's header line: "%name (params) -> shape {" / "ENTRY %name ..."
+_COMP_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+# computations an instruction runs: loop bodies and conditions, branches,
+# called computations (not a fusion's ``calls=``: its ops never reach
+# memory on their own)
+_CALLS_RE = re.compile(r"\b(body|condition|to_apply|branch_computations|"
+                       r"true_computation|false_computation)="
+                       r"(\{[^}]*\}|%?[\w.\-]+)")
+DATA_MOVEMENT_OPS = ("copy", "concatenate", "slice", "pad",
+                     "dynamic-update-slice")
+# what a fusion may hold and still only move data: a fusion made of these
+# alone (and at least one of DATA_MOVEMENT_OPS or a transpose) is a
+# relayout however XLA packaged it. A broadcast is not among them: a
+# fusion that broadcasts a row to the fleet writes new values.
+_MOVE_ONLY = frozenset(DATA_MOVEMENT_OPS) | {
+    "parameter", "constant", "bitcast", "reshape", "transpose",
+    "dynamic-slice", "tuple", "get-tuple-element"}
+_FUSION_CALLS_RE = re.compile(r"\bcalls=%?([\w.\-]+)")
+
+def _computations(hlo_text: str) -> Dict[str, List[str]]:
+    comps: Dict[str, List[str]] = {}
+    cur: Optional[List[str]] = None
+    for line in hlo_text.splitlines():
+        head = None if line[:1].isspace() else _COMP_RE.match(line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return comps
+
+
+def _called(line: str, keys=None) -> List[str]:
+    return [c.strip().lstrip("%") for m in _CALLS_RE.finditer(line)
+            if keys is None or m.group(1) in keys
+            for c in m.group(2).strip("{}").split(",") if c.strip()]
+
+
+def _moves_only(lines: List[str]) -> bool:
+    ops = {m.group(3) for m in map(_DEF_RE.match, lines) if m}
+    return ops <= _MOVE_ONLY and bool(
+        ops & (set(DATA_MOVEMENT_OPS) | {"transpose"}))
+
+
+def loop_data_movement(hlo_text: str) -> List[Tuple[str, str, str, int]]:
+    """``(computation, name, opcode, bytes)`` of every data-movement op
+    (``DATA_MOVEMENT_OPS``) in a non-fusion computation reachable from a
+    ``while`` body, and of every fusion there whose fused computation
+    only moves data (``_MOVE_ONLY``; its opcode reads ``fusion``) — what
+    a compiled program copies each time round a loop."""
+    comps = _computations(hlo_text)
+    todo = [c for lines in comps.values() for line in lines
+            if " while(" in line for c in _called(line, ("body",))]
+    seen, found = set(), []
+    while todo:
+        comp = todo.pop()
+        if comp in seen or comp not in comps:
+            continue
+        seen.add(comp)
+        for line in comps[comp]:
+            m = _DEF_RE.match(line)
+            if m and m.group(3) in DATA_MOVEMENT_OPS:
+                found.append((comp, m.group(1), m.group(3),
+                              _shape_bytes(m.group(2))))
+            elif m and m.group(3) == "fusion":
+                fused = _FUSION_CALLS_RE.search(line)
+                if fused and _moves_only(comps.get(fused.group(1), [])):
+                    found.append((comp, m.group(1), "fusion",
+                                  _shape_bytes(m.group(2))))
+            todo += _called(line)
+    return found

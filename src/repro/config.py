@@ -274,8 +274,8 @@ class ProtocolConfig:
 
     ``layout`` selects the sync arithmetic: ``"tree"`` (default) runs the
     per-leaf pytree expressions, bitwise-identical to the pre-flat
-    engine; ``"flat"`` carries the fleet through the sync stages as one
-    contiguous ``(m, P)`` matrix (``repro.core.flatten``) — parameters
+    engine; ``"flat"`` carries the fleet through the sync stages as the
+    blocked fleet plane (``repro.core.sync.stages``) — parameters
     equal to float-reassociation tolerance, identical sync decisions
     (hence bitwise comm counters) unless a distance lands within
     reassociation error of the Delta threshold, and the balancing

@@ -10,10 +10,12 @@ and the local condition of learner i w.r.t. reference model r is
     || f_i - r ||^2 <= Delta.
 
 ``sq_distance`` optionally routes through the fused Pallas kernel
-(`repro.kernels.ops.sqdist`) — the protocol's monitoring hot-spot.
-``per_learner_sq_distance_flat`` is the batched dual over the flat
-fleet-plane (``repro.core.flatten``): one ``(m, P) x (P,)`` pass, routed
-through the row-tiled Pallas kernel on TPU backends.
+(`repro.kernels.ops.sqdist`). ``per_learner_sq_distance_flat`` is the
+batched dual over a concatenated ``(m, P)`` matrix: one ``(m, P) x (P,)``
+pass, routed through the row-tiled Pallas kernel on TPU backends. The
+engine no longer builds such a matrix — every layout reduces the stacked
+leaves (``per_learner_sq_distance``), which XLA fuses into the local
+update — so the kernel serves only its own tests and benchmarks.
 """
 from __future__ import annotations
 
@@ -34,11 +36,9 @@ def _acc_dtype(dt):
     """Accumulation dtype for reductions over a leaf: at least float32.
 
     Sub-f32 leaves (bfloat16/float16) accumulate in float32 and narrow
-    back, matching the flat fleet-plane (whose dtype is the promotion of
-    the leaf dtypes, at least f32) instead of summing m terms in an
-    8-bit-mantissa format. For float32 leaves every cast below is a
-    no-op, so the pre-fix expressions — and the PR-2 goldens — are
-    reproduced bitwise."""
+    back instead of summing m terms in an 8-bit-mantissa format. For
+    float32 leaves every cast below is a no-op, so the pre-fix
+    expressions — and the PR-2 goldens — are reproduced bitwise."""
     return jnp.promote_types(dt, jnp.float32)
 
 
@@ -48,6 +48,17 @@ def tree_mean(stacked):
     def mean1(x):
         return jnp.mean(x, axis=0, dtype=_acc_dtype(x.dtype)).astype(x.dtype)
     return jax.tree.map(mean1, stacked)
+
+
+def tree_weighted_sum(stacked, weights):
+    """``sum_i w_i f_i`` over the learner axis, per leaf in
+    ``_acc_dtype`` and left there (the balancing loop keeps adding rows
+    to it). weights: (m,)."""
+    def wsum(x):
+        acc = _acc_dtype(x.dtype)
+        w = weights.reshape((-1,) + (1,) * (x.ndim - 1)).astype(acc)
+        return jnp.sum(x.astype(acc) * w, axis=0)
+    return jax.tree.map(wsum, stacked)
 
 
 def tree_weighted_mean(stacked, weights):
@@ -62,18 +73,13 @@ def tree_weighted_mean(stacked, weights):
     back to the leaf dtype: the B^i weights are never downcast to a
     sub-f32 leaf dtype, and the sum over m learners never accumulates in
     bfloat16 — the dtype-promotion contract the static contract checker
-    (``repro.analysis.contracts``) verifies against the flat layout.
+    (``repro.analysis.contracts``) verifies.
     """
     wsum = jnp.sum(weights)
     denom = jnp.where(wsum > 0, wsum, jnp.ones_like(wsum))
-
-    def wmean(x):
-        acc = _acc_dtype(x.dtype)
-        w = weights.reshape((-1,) + (1,) * (x.ndim - 1)).astype(acc)
-        return (jnp.sum(x.astype(acc) * w, axis=0)
-                / denom.astype(acc)).astype(x.dtype)
-
-    return jax.tree.map(wmean, stacked)
+    return jax.tree.map(
+        lambda s, x: (s / denom.astype(s.dtype)).astype(x.dtype),
+        tree_weighted_sum(stacked, weights), stacked)
 
 
 def sq_distance(a, b, use_kernel: bool = False) -> jnp.ndarray:
@@ -93,20 +99,18 @@ def sq_distance(a, b, use_kernel: bool = False) -> jnp.ndarray:
 def per_learner_sq_distance_flat(X, r,
                                  use_kernel: Optional[bool] = None
                                  ) -> jnp.ndarray:
-    """(m,) squared distances over the FLAT fleet-plane: ``X`` is the
-    (m, P) configuration matrix, ``r`` the (P,) reference row.
-
-    This is the protocol's monitoring hot-spot in one batched pass. On a
-    TPU backend it runs the row-tiled Pallas kernel
+    """(m,) squared distances over a concatenated (m, P) configuration
+    matrix ``X`` against the (P,) reference row ``r``, in one batched
+    pass. On a TPU backend it runs the row-tiled Pallas kernel
     (``repro.kernels.ops.sqdist_rows``); elsewhere the kernel would
     execute in interpret mode (Python, orders of magnitude slower than
     XLA), so the dense jnp row reduction is used instead.
     ``use_kernel`` forces the choice either way.
 
-    Under an active fleet (``layout="sharded"``) the kernel runs inside
-    a ``shard_map`` over the ``"fleet"`` axis: each device reduces its
-    own rows against the replicated reference, so the plane is never
-    gathered (a Mosaic kernel cannot be partitioned automatically)."""
+    Under an active fleet the kernel runs inside a ``shard_map`` over
+    the ``"fleet"`` axis: each device reduces its own rows against the
+    replicated reference, so the matrix is never gathered (a Mosaic
+    kernel cannot be partitioned automatically)."""
     if use_kernel is None:
         use_kernel = jax.default_backend() == "tpu"
     if not use_kernel:

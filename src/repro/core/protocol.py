@@ -224,7 +224,7 @@ class DecentralizedLearner:
         # replicated. The jitted round then traces against committed
         # sharded inputs (plus the constrain_rows pins the compiled round
         # inserts under the active fleet below), so per-learner updates,
-        # sqdist rows, and (m, P) commits execute per-shard and only
+        # distance reductions and commits execute per-shard and only
         # trigger votes + cohort means cross devices.
         self.fleet = None
         if self.spec.param("layout") == "sharded":

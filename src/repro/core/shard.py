@@ -1,9 +1,11 @@
 """The device-sharded fleet plane: ``layout="sharded"`` mesh machinery.
 
-``layout="flat"`` (PR 5) already carries the fleet through every sync
-stage as one contiguous ``(m, P)`` matrix — exactly the layout GSPMD
-wants. This module supplies the three pieces that turn that plane into a
-multi-device backend of the SAME ``ProtocolSpec`` compile:
+``layout="flat"`` carries the fleet through every sync stage as the
+blocked fleet plane (``repro.core.sync.stages``): per-leaf blocks,
+each with the learner axis leading — exactly the layout GSPMD wants.
+This module
+supplies the three pieces that turn that plane into a multi-device
+backend of the SAME ``ProtocolSpec`` compile:
 
 * ``FleetSharding`` — a 1-D device mesh with a single ``"fleet"`` axis
   (built through ``repro.compat.make_mesh``, never raw jax) plus the
@@ -18,7 +20,7 @@ multi-device backend of the SAME ``ProtocolSpec`` compile:
 * the **active-fleet context** — ``use_fleet``/``constrain_rows``. The
   compiled round function (``core/sync/spec.py``) is cached per spec and
   knows nothing about devices; under ``layout="sharded"`` it calls
-  ``constrain_rows`` on the raveled plane, which reads the fleet the
+  ``constrain_rows`` on each block of the plane, which reads the fleet the
   ENGINE activated around its jit call (trace-time lookup) and inserts a
   ``with_sharding_constraint`` splitting the m axis over ``"fleet"``.
   With no active fleet — ``jax.eval_shape`` in the static contract gate,
@@ -26,17 +28,20 @@ multi-device backend of the SAME ``ProtocolSpec`` compile:
   so the sharded round stays abstractly bit-identical to ``flat``.
 
 The row gate ``X.shape[0] == fleet.m`` keeps the constraint out of the
-hierarchy's per-cluster vmap (there the plane's leading dim is the
+hierarchy's per-cluster vmap (there a block's leading dim is the
 cluster size k = m/g, and pinning k rows to the fleet axis would be
-wrong); per-cluster sync then runs with flat arithmetic while the fleet
+wrong); per-cluster sync then runs with plane arithmetic while the fleet
 carry around it stays device-sharded.
 
 Everything device-visible goes through the fleet's mesh, so the sharded
-layout executes per-shard: the per-learner update and ``sqdist_rows``
-(each inside a ``shard_map``, :func:`per_shard`), the ``(m, P)`` commits
-and the per-link ledger rows are local to each device's row block, and
-only the trigger vote (an ``any()`` over (m,) scalars) and the cohort
-means (one ``w @ X`` matvec) cross devices.
+layout executes per-shard: the per-learner update (inside a
+``shard_map``, :func:`per_shard`), the distance reductions over each
+block's trailing axes, the commits and the per-link ledger rows are
+local to each device's learners, and only the trigger vote (an
+``any()`` over (m,) scalars) and the cohort means (a sum over the
+learner axis per block) cross devices — plus, in a partial sync, one
+all-gather of each block per balancing step, where the loop indexes a
+learner's row out of the sharded blocks.
 """
 from __future__ import annotations
 

@@ -42,10 +42,9 @@ plus specs, zero kernel/engine edits. Three pieces:
   the mean plus Gaussian noise at ``snr_db`` relative to the
   aggregate's RMS, attenuated by the cohort size (n aligned
   transmissions add amplitudes, the receiver noise does not). The draw
-  is pure in ``(air_seed, t)``. Noise is drawn per leaf on the tree
-  layout and once over the plane row on flat/sharded layouts, so
-  parameters are layout-consistent only per layout family; counters
-  and ledger are layout-invariant as always.
+  is pure in ``(air_seed, t)`` and drawn per leaf on every layout (the
+  plane's row is the model pytree); counters and ledger are
+  layout-invariant as always.
 
 * **``"aircomp"`` commit** — the pricing dual: one shared-medium
   transmission in the paper's c(f) (``model_up = model_down = 1`` per
@@ -64,9 +63,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.divergence import (
-    per_learner_sq_distance, per_learner_sq_distance_flat,
-)
 from repro.core.sync.kernel import register_protocol
 from repro.core.sync.registry import (
     CohortOut, CommRecord, StageContract, StageCtx, SyncOut, carried_v,
@@ -74,8 +70,8 @@ from repro.core.sync.registry import (
 )
 from repro.core.sync.spec import ProtocolSpec
 from repro.core.sync.stages import (
-    _broadcast_commit, _ref_if_commit, _select_commit, _validate_b,
-    aggregate_mean_stage, zeros_i32,
+    _ref_dists, _ref_if_commit, _select_commit, _validate_b,
+    aggregate_mean_stage, broadcast_model, zeros_i32,
 )
 from repro.network import events
 
@@ -236,12 +232,6 @@ def trigger_events(ctx: StageCtx):
 # trigger "events_divergence": sigma_Delta's condition on the local clock
 # ---------------------------------------------------------------------------
 
-def _div_dists(ctx: StageCtx):
-    if ctx.flat is not None:
-        return per_learner_sq_distance_flat(ctx.flat, ctx.ref_flat)
-    return per_learner_sq_distance(ctx.stacked, ctx.state.ref)
-
-
 def _events_div_alarm(ctx: StageCtx, tl: dict, dists):
     violated = (dists > ctx.params["delta"]) & ctx.reach
     return violated & tl["tick"] & tl["idle"] & ~tl["due"]
@@ -249,7 +239,7 @@ def _events_div_alarm(ctx: StageCtx, tl: dict, dists):
 
 def _events_div_condition(ctx: StageCtx):
     tl = _timeline(ctx)
-    dists = _div_dists(ctx)
+    dists = _ref_dists(ctx)
     alarm = _events_div_alarm(ctx, tl, dists)
     launch = alarm & (tl["k"] > 0)
     fire = (tl["due"] & ctx.reach) | (alarm & (tl["k"] == 0))
@@ -268,7 +258,7 @@ def _events_div_launch(ctx: StageCtx, tl: dict):
     documented extra cost of divergence monitoring on non-sync rounds."""
     if isinstance(ctx.cond_aux, dict) and "launch" in ctx.cond_aux:
         return ctx.cond_aux["launch"]
-    return _events_div_alarm(ctx, tl, _div_dists(ctx)) & (tl["k"] > 0)
+    return _events_div_alarm(ctx, tl, _ref_dists(ctx)) & (tl["k"] > 0)
 
 
 def _events_div_commit(ctx: StageCtx, mask) -> dict:
@@ -350,8 +340,6 @@ def aggregate_aircomp(ctx: StageCtx, cout: CohortOut):
                                 jnp.float32)
         return (xf + (rms * scale / n) * eps).astype(x.dtype)
 
-    if ctx.flat is not None:
-        return noisy(0, mean)
     leaves, treedef = jax.tree.flatten(mean)
     return jax.tree.unflatten(
         treedef, [noisy(i, x) for i, x in enumerate(leaves)])
@@ -375,7 +363,7 @@ def commit_aircomp(ctx: StageCtx, cout: CohortOut, mean, hot,
     fleet throughput."""
     m = ctx.m
     if cout.ideal:
-        newcfg = _broadcast_commit(ctx, mean, m)
+        newcfg = broadcast_model(mean, m)
         rec = CommRecord(
             model_up=jnp.int32(1), model_down=jnp.int32(1),
             messages=jnp.int32(0), syncs=jnp.int32(1),

@@ -31,11 +31,10 @@ for reporting, but with mixed payload sizes the ledger — not
 ``transfers × model_bytes`` — is the source of truth for bytes.
 
 Layout: an intra (or inter) spec with ``layout="flat"`` runs its staged
-round on the flat fleet-plane INSIDE this composition with no edits here
-— the compiled round ravels per cluster under the intra ``vmap`` (the
-plane becomes a batched (g, k, P) matmul) and unravels before the stage
-boundary, so the aggregator means, down-push and per-tier accounting
-below always see pytrees.
+round on the blocked fleet plane INSIDE this composition with no edits
+here — under the intra ``vmap`` each block gains the cluster axis, and
+the blocks are the stacked leaves themselves, so the aggregator means,
+down-push and per-tier accounting below always see pytrees.
 """
 from __future__ import annotations
 

@@ -39,8 +39,8 @@ preserves the pre-network engine's numerics bitwise.
 
 Layout (the global ``layout`` spec param, ``ProtocolConfig.layout``
 sugar): every preset runs either on the per-leaf pytree expressions
-(``"tree"``, the default — bitwise vs the goldens) or on the flat
-(m, P) fleet-plane (``"flat"``, ``repro.core.flatten`` — params to
+(``"tree"``, the default — bitwise vs the goldens) or on the blocked
+fleet plane (``"flat"``, ``repro.core.sync.stages`` — params to
 float-reassociation tolerance, identical sync decisions hence bitwise
 comm counters away from razor-edge threshold ties, balancing in
 O(m*P)). The same registered stages serve both; no preset is

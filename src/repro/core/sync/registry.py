@@ -55,7 +55,7 @@ the static contract checker (``repro.analysis.contracts``) verifies by
 abstract evaluation across every preset × layout × hierarchy combination:
 counters stay int32, masks stay bool, the committed configuration keeps
 the input dtypes exactly, aggregate outputs match their declared kind
-((P,)/model vs (m, P)/fleet duals), and trigger-owned extra state keeps
+(a model row vs an (m, ...) fleet), and trigger-owned extra state keeps
 its declared dtypes through commit and skip paths. The repo lint
 (``repro.analysis.lint``) rejects ``register_*`` calls without one.
 """
@@ -107,13 +107,13 @@ class StageResult(NamedTuple):
 class StageCtx(NamedTuple):
     """One round's inputs, shared by every stage.
 
-    Under ``layout="flat"`` the compiled round additionally carries the
-    flat fleet-plane (``repro.core.flatten``): ``flat`` is the whole
-    configuration as one (m, P) matrix, ``ref_flat`` the reference model
-    as its (P,) row, and ``adapter`` the static ravel/unravel maps.
-    Stages branch on ``ctx.flat is not None`` to run their dense-matrix
-    form; under the default ``layout="tree"`` all three stay ``None`` and
-    the per-leaf pytree expressions run bitwise-unchanged. ``cond_aux``
+    Under the plane layouts (``"flat"``/``"sharded"``) the compiled round
+    hands the stages the blocked fleet plane (``repro.core.sync.stages``):
+    ``stacked`` is then the same leaves, each pinned on its learner axis,
+    and ``plane`` is set; stages branch on it only where the plane runs
+    its own algorithm (the incremental balancing loop). Under the default
+    ``layout="tree"`` it stays False and the per-leaf pytree expressions
+    run bitwise-unchanged. ``cond_aux``
     carries whatever a conditional trigger computed beyond (hot, nhot) —
     e.g. the divergence trigger's (m,) distances, which the balancing
     cohort reuses as its augmentation priority instead of recomputing
@@ -127,9 +127,7 @@ class StageCtx(NamedTuple):
     m: int                           # fleet size (static)
     t: jnp.ndarray                   # this round's index (state.step + 1)
     reach: jnp.ndarray               # (m,) bool; all-ones when active=None
-    flat: Optional[jnp.ndarray] = None      # (m, P) plane (flat layout)
-    ref_flat: Optional[jnp.ndarray] = None  # (P,) reference row
-    adapter: Any = None              # static FleetAdapter (flat layout)
+    plane: bool = False              # the plane layouts' blocked plane
     cond_aux: Any = None             # trigger-condition extras (e.g. dists)
 
 
@@ -187,9 +185,9 @@ class StageContract(NamedTuple):
       (returns a scalar int32 ``v`` and a scalar bool ``full``;
       everything else must leave both ``None``). ``aux``: the keys of
       ``CohortOut.aux``.
-    * **aggregate** — ``out``: ``"model"`` (a single-model pytree on the
-      tree layout / a (P,) row on the plane) or ``"fleet"`` (an (m, ...)
-      stacked pytree / the full (m, P) plane).
+    * **aggregate** — ``out``: ``"model"`` (a single-model pytree, the
+      plane's row) or ``"fleet"`` (an (m, ...) stacked pytree, the
+      plane's blocks).
 
     Universal promises (not declarable — always enforced): the committed
     configuration and reference keep the input shapes AND dtypes bitwise,

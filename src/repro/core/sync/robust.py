@@ -12,8 +12,8 @@ stages — zero kernel/engine edits, the PR-4 contract:
   ``trim_frac`` smallest and largest finite values per coordinate, mean
   the rest) and ``median``. Both are finite-guarded: a NaN/Inf entry is
   simply excluded from its coordinate's order statistics, so corrupted
-  payloads cannot poison the aggregate. Both have tree and flat/sharded
-  duals behind one registration and ignore Algorithm-2 weights by
+  payloads cannot poison the aggregate. Both reduce each leaf along its
+  learner axis, on every layout, and ignore Algorithm-2 weights by
   design (weighting by self-reported sample counts is itself an attack
   surface — an adversary would just claim the largest B^i).
 * **the ``quarantine`` commit** — flags suspect cohort rows (any
@@ -50,9 +50,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.divergence import (
-    per_learner_sq_distance, per_learner_sq_distance_flat,
-)
+from repro.core.divergence import _acc_dtype
 from repro.core.sync.registry import (
     CohortOut, CommRecord, StageContract, StageCtx, SyncOut, carried_v,
     register_aggregate, register_commit, register_trigger,
@@ -60,9 +58,9 @@ from repro.core.sync.registry import (
 from repro.core.sync.spec import ProtocolSpec
 from repro.core.sync.kernel import register_protocol
 from repro.core.sync.stages import (
-    _broadcast_commit, _divergence_condition, _ref_if_commit,
-    _select_commit, _validate_b, _validate_delta, broadcast_model,
-    cadence_fire, tree_select, xfers_cohort, zeros_i32,
+    _divergence_condition, _ref_dists, _ref_if_commit, _select_commit,
+    _validate_b, _validate_delta, broadcast_model, cadence_fire,
+    tree_select, xfers_cohort, zeros_i32,
 )
 
 # absolute slack on the outlier threshold so a perfectly-converged
@@ -78,20 +76,11 @@ _SUSPECT_EPS = 1e-12
 
 def _finite_rows(ctx: StageCtx) -> jnp.ndarray:
     """(m,) bool — rows whose every parameter is finite."""
-    if ctx.flat is not None:
-        return jnp.all(jnp.isfinite(ctx.flat), axis=1)
     finite = None
     for leaf in jax.tree.leaves(ctx.stacked):
-        f = jnp.all(jnp.isfinite(leaf.reshape(leaf.shape[0], -1)), axis=1)
+        f = jnp.all(jnp.isfinite(leaf), axis=tuple(range(1, leaf.ndim)))
         finite = f if finite is None else finite & f
     return finite
-
-
-def _row_dists(ctx: StageCtx) -> jnp.ndarray:
-    """(m,) f32 squared distances to the reference model."""
-    if ctx.flat is not None:
-        return per_learner_sq_distance_flat(ctx.flat, ctx.ref_flat)
-    return per_learner_sq_distance(ctx.stacked, ctx.state.ref)
 
 
 def _masked_median(x: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
@@ -111,7 +100,7 @@ def _suspect_rows(ctx: StageCtx, mask: jnp.ndarray) -> jnp.ndarray:
     cohort — at >= 50% adversaries the median itself is captured, the
     classical breakdown point."""
     finite = _finite_rows(ctx)
-    d = _row_dists(ctx)
+    d = _ref_dists(ctx)
     med = _masked_median(d, mask & finite)
     far = d > ctx.params["quarantine_mult"] * med + _SUSPECT_EPS
     return mask & (~finite | far)
@@ -230,11 +219,16 @@ def trigger_robust_divergence(ctx: StageCtx):
 # robust aggregates: coordinate-wise trimmed mean and median
 # ---------------------------------------------------------------------------
 
+def _rows(v: jnp.ndarray, ndim: int) -> jnp.ndarray:
+    """An (m,) vector shaped to broadcast along a block's learner axis."""
+    return v.reshape((-1,) + (1,) * (ndim - 1))
+
+
 def _sorted_valid(X: jnp.ndarray, mask: jnp.ndarray):
-    """Per-coordinate ascending sort of the masked FINITE entries
-    (invalid entries pushed to the end as +inf) and the (P,) count of
-    valid entries per coordinate."""
-    valid = mask[:, None] & jnp.isfinite(X)
+    """Per-coordinate ascending sort (along the learner axis 0) of the
+    masked FINITE entries (invalid entries pushed to the end as +inf)
+    and the count of valid entries per coordinate."""
+    valid = _rows(mask, X.ndim) & jnp.isfinite(X)
     order = jnp.sort(jnp.where(valid, X, jnp.inf), axis=0)
     n = jnp.sum(valid, axis=0).astype(jnp.int32)
     return order, n
@@ -242,16 +236,16 @@ def _sorted_valid(X: jnp.ndarray, mask: jnp.ndarray):
 
 def flat_trimmed_mean(X: jnp.ndarray, mask: jnp.ndarray,
                       trim_frac: float) -> jnp.ndarray:
-    """Coordinate-wise trimmed mean over the plane's masked rows: per
-    coordinate, drop the ``floor(trim_frac * n)`` smallest and largest
-    finite values and mean the rest. ``trim_frac=0`` is the plain
+    """Coordinate-wise trimmed mean over the masked rows of an (m, ...)
+    block: per coordinate, drop the ``floor(trim_frac * n)`` smallest
+    and largest finite values and mean the rest. ``trim_frac=0`` is the plain
     finite-guarded mean (to reassociation tolerance: the sum runs in
     sorted order). An all-invalid coordinate yields 0 — commits keep
     the previous configuration via their selects."""
     order, n = _sorted_valid(X, mask)
     k = jnp.floor(trim_frac * n.astype(X.dtype)).astype(jnp.int32)
-    idx = jnp.arange(X.shape[0], dtype=jnp.int32)[:, None]
-    keep = (idx >= k[None, :]) & (idx < (n - k)[None, :])
+    idx = _rows(jnp.arange(X.shape[0], dtype=jnp.int32), X.ndim)
+    keep = (idx >= k[None]) & (idx < (n - k)[None])
     cnt = jnp.maximum(n - 2 * k, 1).astype(X.dtype)
     out = jnp.sum(jnp.where(keep, order, jnp.zeros_like(order)),
                   axis=0) / cnt
@@ -259,25 +253,25 @@ def flat_trimmed_mean(X: jnp.ndarray, mask: jnp.ndarray,
 
 
 def flat_median(X: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
-    """Coordinate-wise median over the plane's masked finite entries
-    (midpoint of the two central order statistics for even counts)."""
+    """Coordinate-wise median over the masked finite entries of an
+    (m, ...) block (midpoint of the two central order statistics for
+    even counts)."""
     order, n = _sorted_valid(X, mask)
-    lo = jnp.take_along_axis(order, jnp.maximum((n - 1) // 2, 0)[None, :],
+    lo = jnp.take_along_axis(order, jnp.maximum((n - 1) // 2, 0)[None],
                              axis=0)[0]
-    hi = jnp.take_along_axis(order, (n // 2)[None, :], axis=0)[0]
+    hi = jnp.take_along_axis(order, (n // 2)[None], axis=0)[0]
     out = jnp.asarray(0.5, X.dtype) * (lo + hi)
     return jnp.where(n > 0, out, jnp.zeros_like(out))
 
 
-def _tree_rowwise(stacked, fn):
-    """Tree dual of a per-coordinate plane aggregate: each leaf runs the
-    plane form on its own (m, cols) view in the promoted accumulation
-    dtype (at least f32) and narrows back to the leaf dtype."""
-    def leaf(x):
-        acc = jnp.promote_types(x.dtype, jnp.float32)
-        out = fn(x.reshape(x.shape[0], -1).astype(acc))
-        return out.astype(x.dtype).reshape(x.shape[1:])
-    return jax.tree.map(leaf, stacked)
+def _per_coordinate(ctx: StageCtx, fn):
+    """A per-coordinate aggregate, leaf by leaf: ``fn`` reduces each
+    (m, ...) leaf along its learner axis, with no reshape, in the leaf's
+    accumulation dtype (at least f32), narrowed back to the leaf
+    dtype."""
+    return jax.tree.map(
+        lambda x: fn(x.astype(_acc_dtype(x.dtype))).astype(x.dtype),
+        ctx.stacked)
 
 
 def _validate_trim(params):
@@ -300,9 +294,7 @@ def aggregate_trimmed_mean(ctx: StageCtx, cout: CohortOut):
     coordinate; unweighted by design (see the module docstring)."""
     tf = ctx.params["trim_frac"]
     mask = jnp.ones((ctx.m,), bool) if cout.ideal else cout.mask
-    if ctx.flat is not None:
-        return flat_trimmed_mean(ctx.flat, mask, tf)
-    return _tree_rowwise(ctx.stacked, lambda P: flat_trimmed_mean(P, mask, tf))
+    return _per_coordinate(ctx, lambda X: flat_trimmed_mean(X, mask, tf))
 
 
 @register_aggregate("median", contract=StageContract(
@@ -313,9 +305,7 @@ def aggregate_median(ctx: StageCtx, cout: CohortOut):
     """Coordinate-wise median of the cohort — the maximal trim, robust
     up to (but not at) 50% arbitrary values per coordinate."""
     mask = jnp.ones((ctx.m,), bool) if cout.ideal else cout.mask
-    if ctx.flat is not None:
-        return flat_median(ctx.flat, mask)
-    return _tree_rowwise(ctx.stacked, lambda P: flat_median(P, mask))
+    return _per_coordinate(ctx, lambda X: flat_median(X, mask))
 
 
 # ---------------------------------------------------------------------------
@@ -325,8 +315,6 @@ def aggregate_median(ctx: StageCtx, cout: CohortOut):
 def _quarantine_select(ctx: StageCtx, bad, newcfg):
     """Suspect rows are warm-started from the reference model instead of
     adopting the committed configuration."""
-    if ctx.flat is not None:
-        return jnp.where(bad[:, None], ctx.ref_flat[None, :], newcfg)
     return tree_select(bad, broadcast_model(ctx.state.ref, ctx.m), newcfg)
 
 
@@ -350,8 +338,7 @@ def commit_quarantine(ctx: StageCtx, cout: CohortOut, mean, hot,
     m = ctx.m
     if cout.ideal:
         bad = _suspect_rows(ctx, jnp.ones((m,), bool))
-        newcfg = _quarantine_select(ctx, bad,
-                                    _broadcast_commit(ctx, mean, m))
+        newcfg = _quarantine_select(ctx, bad, broadcast_model(mean, m))
         rec = CommRecord(
             model_up=jnp.int32(m), model_down=jnp.int32(m),
             messages=jnp.int32(0), syncs=jnp.int32(1),

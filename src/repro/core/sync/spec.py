@@ -34,7 +34,7 @@ from typing import Any, Dict, Tuple
 
 import jax
 
-from repro.core import flatten, shard
+from repro.core import shard
 from repro.core.sync import registry, stages
 from repro.core.sync.registry import (
     CommRecord, StageCtx, StageResult, SyncOut, get_protocol,
@@ -42,12 +42,12 @@ from repro.core.sync.registry import (
 
 # parameters every spec understands regardless of its stages. ``layout``
 # picks the fleet arithmetic: "tree" (per-leaf pytree expressions,
-# bitwise vs the goldens), "flat" (the one-(m, P)-matrix fleet plane,
-# repro.core.flatten — params to float reassociation tolerance, and the
+# bitwise vs the goldens), "flat" (the blocked fleet plane,
+# repro.core.sync.stages — params to float reassociation tolerance, and the
 # same sync decisions hence bitwise comm counters, except in the
 # measure-zero case where a distance lands within reassociation error
 # of the Delta threshold and the differently-associated sums disagree
-# about the comparison), or "sharded" (the same flat plane with its m
+# about the comparison), or "sharded" (the same plane with its m
 # axis split over a device mesh, repro.core.shard — identical arithmetic
 # to "flat"; ``shard_devices`` caps how many visible devices the fleet
 # mesh uses, 0 = all of them, and m % n_devices must be 0)
@@ -60,10 +60,18 @@ GLOBAL_PARAMS: Dict[str, Any] = {"weighted": False, "bytes_per_param": 4,
 # abstract tree-equivalence automatically.
 LAYOUTS = ("tree", "flat", "sharded")
 
-# layouts that run the dense (m, P) fleet-plane arithmetic. "sharded" is
+# layouts that run the blocked fleet-plane arithmetic. "sharded" is
 # "flat" plus sharding constraints that are identity off-mesh, so both
 # the compile below and the contract checker treat them as one family.
 PLANE_LAYOUTS = ("flat", "sharded")
+
+
+def pin_rows(stacked):
+    """The blocked fleet plane of a stacked configuration: the same
+    leaves, each pinned on its learner axis (``shard.constrain_rows``;
+    the identity with no active fleet). Moves no data."""
+    return jax.tree.map(shard.constrain_rows, stacked)
+
 
 # the ProtocolConfig fields that overlay onto a preset's params (only the
 # ones the preset's stages actually consume are applied)
@@ -283,19 +291,18 @@ def _compiled_round(spec: ProtocolSpec):
                     cohort -> aggregate -> commit
           false: identity + zero accounting (extra state still ages)
 
-    Under the plane layouts ("flat"/"sharded") the gated branch
-    additionally ravels the configuration onto the flat fleet-plane
-    (``repro.core.flatten``) — the stages then run their dense (m, P)
-    forms and the committed plane is unraveled back to the pytree before
-    the branches join, so the scan carry (and everything outside the
-    sync machinery) keeps the pytree layout either way. A round whose
-    gate does not fire never pays for the ravel. ``layout="sharded"``
-    runs the identical plane arithmetic and only adds
-    ``shard.constrain_rows`` pins on the raveled and committed planes:
-    at trace time they read the fleet mesh the ENGINE activated
-    (``shard.use_fleet``) and split the m axis over its devices; with no
-    active fleet (eval_shape in the contract gate, the jaxpr audit) they
-    are the identity, so "sharded" stays abstractly equal to "flat".
+    Under the plane layouts ("flat"/"sharded") the stages run their
+    blocked fleet-plane forms (``repro.core.sync.stages``): the plane is
+    the stacked leaves themselves and the row the reference pytree, so
+    nothing is raveled before the condition or unraveled after the
+    commit, and the scan carry keeps one layout whether the gate fires
+    or not. ``layout="sharded"`` runs the identical arithmetic and only
+    pins each leaf's learner axis (``shard.constrain_rows``) on the way
+    in and out: at trace time the pins read the fleet mesh the ENGINE
+    activated (``shard.use_fleet``) and split the m axis over its
+    devices; with no active fleet (eval_shape in the contract gate, the
+    jaxpr audit) they are the identity, so "sharded" stays abstractly
+    equal to "flat".
     """
     trig, coh, agg, com = spec.stage_records()
     p = spec.resolved_params()
@@ -305,10 +312,9 @@ def _compiled_round(spec: ProtocolSpec):
         m = stages.num_learners(stacked)
         t = state.step + 1
         reach = stages.cohort_all(m, active)
-        adapter = flatten.fleet_adapter(stacked) if flat_layout else None
         ctx = StageCtx(params=p, stacked=stacked, state=state,
                        weights=weights, active=active, adjacency=adjacency,
-                       m=m, t=t, reach=reach, adapter=adapter)
+                       m=m, t=t, reach=reach)
 
         def skip_out(rng):
             return SyncOut(stacked, state.ref, state.v, rng,
@@ -323,18 +329,14 @@ def _compiled_round(spec: ProtocolSpec):
             with jax.named_scope("repro.sync.commit"):
                 out = com.fn(sctx, cout, agg_out, hot, nhot)
                 out = out._replace(extra=trig.commit_extra(sctx, cout.mask))
-            if adapter is not None:
-                out = out._replace(
-                    params=adapter.unravel(shard.constrain_rows(out.params)),
-                    ref=adapter.unravel_model(out.ref))
+            if flat_layout:
+                out = out._replace(params=pin_rows(out.params))
             return out
 
         def sync(rng):
             sctx = ctx
-            if adapter is not None:
-                sctx = ctx._replace(
-                    flat=shard.constrain_rows(adapter.ravel(stacked)),
-                    ref_flat=adapter.ravel_model(state.ref))
+            if flat_layout:
+                sctx = ctx._replace(stacked=pin_rows(stacked), plane=True)
             if trig.condition is None:
                 return pipeline(sctx, reach, None, rng)
             with jax.named_scope("repro.sync.condition"):

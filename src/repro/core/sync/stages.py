@@ -24,16 +24,21 @@ half registers the built-in stages into the named registries
 composes; the six preset protocols in ``kernel.py`` are nothing but
 spec-level wirings of these registrations.
 
-Every registered stage has TWO arithmetic forms behind one registration:
-the per-leaf pytree expressions (``layout="tree"``, the default, bitwise
-vs the goldens) and the dense matrix form over the flat fleet-plane
-(``layout="flat"``, see ``repro.core.flatten``), selected by
-``ctx.flat is not None``. On the plane the per-learner distances are one
-batched row pass, the masked weighted mean is one ``w @ X`` matvec,
-gossip's mixing step is one ``W @ X`` matmul, commits are one
-``jnp.where`` on (m, P) — and the balancing augmentation maintains an
-incremental running sum so each iteration costs O(P) instead of a full
-O(m*P) fleet re-aggregation.
+Under the plane layouts (``layout="flat"``/``"sharded"``) the stages
+run on the *blocked fleet plane*: the stacked leaves themselves, each
+block ``(m, *leaf_shape)`` pinned on its learner axis
+(``repro.core.shard.constrain_rows``, the identity off-mesh) and carried
+as ``ctx.stacked`` with ``ctx.plane`` set; a row — the reference, an
+aggregate — is the model pytree. The leaves are never concatenated into
+one ``(m, P)`` matrix: on TPU the ``(8, 128)`` tiling of such a matrix
+puts eight learners in each tile while a block keeps each learner's slab
+contiguous, so every conversion between the two is a relayout of the
+whole fleet. Distances, means, commits and gossip's mixing step are
+therefore the same per-leaf expressions on every layout (XLA fuses the
+distance reduction into the local update's last write of each block).
+``ctx.plane`` selects the plane's one algorithm of its own: the
+balancing augmentation keeps an incremental running row sum, so each
+iteration costs O(P) instead of a full O(m*P) fleet re-aggregation.
 """
 from __future__ import annotations
 
@@ -43,8 +48,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.divergence import (
-    per_learner_sq_distance, per_learner_sq_distance_flat, tree_mean,
-    tree_weighted_mean,
+    per_learner_sq_distance, sq_distance, tree_mean, tree_weighted_mean,
+    tree_weighted_sum,
 )
 from repro.core.sync.registry import (
     CohortOut, CommRecord, StageContract, StageCtx, SyncOut, carried_v,
@@ -78,61 +83,21 @@ def broadcast_model(model, m: int):
                         model)
 
 
-# ---------------------------------------------------------------------------
-# flat fleet-plane arithmetic (layout="flat"; repro.core.flatten)
-# ---------------------------------------------------------------------------
+# stage-internal helpers ------------------------------------------------------
 
-def flat_weighted_mean(X, w):
-    """Masked/weighted mean over the plane's rows: ``w @ X / sum(w)`` —
-    ONE matvec for the whole fleet. Same all-zero guard as
-    ``tree_weighted_mean``: an empty weight vector yields the zero row."""
-    w = w.astype(X.dtype)
-    wsum = jnp.sum(w)
-    denom = jnp.where(wsum > 0, wsum, jnp.ones_like(wsum))
-    return (w @ X) / denom
-
-
-def flat_aggregate_mean(X, mask, weights=None):
-    """The plane dual of ``aggregate_mean``."""
-    w = mask.astype(X.dtype)
-    if weights is not None:
-        w = w * weights.astype(X.dtype)
-    return flat_weighted_mean(X, w)
-
-
-def _flat_sq_to_ref(row, ref):
-    d = row - ref
-    return jnp.sum(d * d)
-
-
-# stage-internal helpers: pick the arithmetic form the ctx carries -----------
-
-def _cfg_view(ctx):
-    return ctx.flat if ctx.flat is not None else ctx.stacked
-
-
-def _ref_view(ctx):
-    return ctx.ref_flat if ctx.flat is not None else ctx.state.ref
+def _ref_dists(ctx):
+    """(m,) f32 squared distances of the configuration to the reference."""
+    return per_learner_sq_distance(ctx.stacked, ctx.state.ref)
 
 
 def _select_commit(ctx, mask, mean):
-    """Cohort members adopt the aggregate, on whichever layout the round
-    carries (one (m, P) ``jnp.where`` on the plane)."""
-    if ctx.flat is not None:
-        return jnp.where(mask[:, None], mean[None, :], ctx.flat)
+    """Cohort members adopt the aggregate: one per-learner select per
+    leaf, on whichever configuration the round carries."""
     return commit_select(ctx.stacked, mask, mean)
 
 
 def _ref_if_commit(ctx, moved, mean):
-    if ctx.flat is not None:
-        return jnp.where(moved, mean, ctx.ref_flat)
     return commit_ref_if(moved, mean, ctx.state.ref)
-
-
-def _broadcast_commit(ctx, mean, m: int):
-    if ctx.flat is not None:
-        return jnp.broadcast_to(mean[None, :], (m,) + mean.shape)
-    return broadcast_model(mean, m)
 
 
 def _cond_dists(ctx):
@@ -246,38 +211,39 @@ def cohort_balanced(delta: float, augmentation: str, stacked, ref, violated,
 
 def cohort_balanced_flat(delta: float, augmentation: str, X, ref, violated,
                          rng, weights=None, reach=None, dists=None):
-    """The balancing augmentation on the flat fleet-plane, with an
+    """The balancing augmentation on the blocked fleet-plane, with an
     INCREMENTAL running sum: the loop carries ``(sum_B, wsum_B)`` and each
-    augmentation step adds one row (``sum += w[nxt] * X[nxt]``) and tests
-    the safe zone on ``||sum/wsum - r||^2`` — O(P) per iteration, so the
-    whole balancing pass is O(m*P) instead of the tree layout's
-    O(m^2*P) worst case (a full fleet re-aggregation per step).
+    augmentation step adds one row (``sum += w[nxt] * X[nxt]``, block by
+    block) and tests the safe zone on ``||sum/wsum - r||^2`` — O(P) per
+    iteration, so the whole balancing pass is O(m*P) instead of the tree
+    layout's O(m^2*P) worst case (a full fleet re-aggregation per step).
 
     Same contract as ``cohort_balanced``: returns ``(mask B, mean_B)``
-    with the final mean recomputed as one masked matvec (matching the
-    aggregate stage's expression, not the running sum's association)."""
-    m = X.shape[0]
+    with the final mean recomputed as the aggregate stage's masked mean,
+    not the running sum's association."""
+    m = num_learners(X)
     if reach is None:
         reach = jnp.ones((m,), bool)
 
     if augmentation == "all":   # jump straight to full sync: no priority
-        return reach, flat_aggregate_mean(X, reach, weights)
+        return reach, aggregate_mean(X, reach, weights)
 
     if augmentation == "random":
         prio = jax.random.uniform(rng, (m,))
     else:  # "max_distance"
-        prio = (per_learner_sq_distance_flat(X, ref) if dists is None
+        prio = (per_learner_sq_distance(X, ref) if dists is None
                 else dists)
 
-    w = (weights.astype(X.dtype) if weights is not None
-         else jnp.ones((m,), X.dtype))
+    w = (weights.astype(jnp.float32) if weights is not None
+         else jnp.ones((m,), jnp.float32))
 
     def safe_dist(s, ws):
         denom = jnp.where(ws > 0, ws, jnp.ones_like(ws))
-        return _flat_sq_to_ref(s / denom, ref)
+        return sq_distance(
+            jax.tree.map(lambda a: a / denom.astype(a.dtype), s), ref)
 
-    w0 = violated.astype(X.dtype) * w
-    s0 = w0 @ X
+    w0 = violated.astype(jnp.float32) * w
+    s0 = tree_weighted_sum(X, w0)
     ws0 = jnp.sum(w0)
 
     def cond(carry):
@@ -289,13 +255,15 @@ def cohort_balanced_flat(delta: float, augmentation: str, X, ref, violated,
         cand = jnp.where(mask | ~reach, -jnp.inf, prio)
         nxt = jnp.argmax(cand)
         mask = mask.at[nxt].set(True)
-        s = s + w[nxt] * X[nxt]
+        s = jax.tree.map(
+            lambda a, x: a + w[nxt].astype(a.dtype) * x[nxt].astype(a.dtype),
+            s, X)
         ws = ws + w[nxt]
         return mask, s, ws, safe_dist(s, ws)
 
     mask, _, _, _ = jax.lax.while_loop(
         cond, body, (violated, s0, ws0, safe_dist(s0, ws0)))
-    return mask, flat_aggregate_mean(X, mask, weights)
+    return mask, aggregate_mean(X, mask, weights)
 
 
 def cohort_neighborhood(m: int, active: Optional[jnp.ndarray], adjacency):
@@ -434,13 +402,8 @@ def trigger_cadence(ctx: StageCtx):
 
 
 def _divergence_condition(ctx: StageCtx):
-    if ctx.flat is not None:
-        dists = per_learner_sq_distance_flat(ctx.flat, ctx.ref_flat)
-        violated = (dists > ctx.params["delta"]) & ctx.reach
-        nviol = jnp.sum(violated).astype(jnp.int32)
-    else:
-        dists, violated, nviol = divergence_trigger(
-            ctx.params["delta"], ctx.stacked, ctx.state.ref, ctx.reach)
+    dists, violated, nviol = divergence_trigger(
+        ctx.params["delta"], ctx.stacked, ctx.state.ref, ctx.reach)
     # the distances double as the balancing cohort's augmentation
     # priority — thread them so the round pays for the monitoring pass
     # exactly once
@@ -508,11 +471,10 @@ def cohort_balanced_stage(ctx: StageCtx, hot, nhot, rng) -> CohortOut:
     force_full = v_new >= ctx.m
     base = jnp.where(force_full, ctx.reach, hot)
     v_reset = jnp.where(force_full, jnp.int32(0), v_new)
-    balance = (cohort_balanced_flat if ctx.flat is not None
-               else cohort_balanced)
+    balance = cohort_balanced_flat if ctx.plane else cohort_balanced
     mask, _ = balance(
-        ctx.params["delta"], ctx.params["augmentation"], _cfg_view(ctx),
-        _ref_view(ctx), base, sub, ctx.weights, ctx.reach,
+        ctx.params["delta"], ctx.params["augmentation"], ctx.stacked,
+        ctx.state.ref, base, sub, ctx.weights, ctx.reach,
         dists=_cond_dists(ctx))
     full = jnp.all(mask == ctx.reach)
     v_final = jnp.where(full, jnp.int32(0), v_reset)
@@ -545,13 +507,7 @@ def cohort_neighborhood_stage(ctx: StageCtx, hot, nhot, rng) -> CohortOut:
 def aggregate_mean_stage(ctx: StageCtx, cout: CohortOut):
     """Masked (weighted) mean of the cohort; the full-fleet ideal path
     (``cout.ideal``) keeps the pre-network ``tree_mean`` expression
-    bitwise. On the flat plane both paths are one matvec (the ideal
-    unweighted one a plain row mean)."""
-    if ctx.flat is not None:
-        if cout.ideal and ctx.weights is None:
-            return jnp.mean(ctx.flat, axis=0)
-        mask = (jnp.ones((ctx.m,), bool) if cout.ideal else cout.mask)
-        return flat_aggregate_mean(ctx.flat, mask, ctx.weights)
+    bitwise. The same per-leaf expressions run on the blocked plane."""
     if cout.ideal:
         return aggregate_mean_ideal(ctx.stacked, ctx.m, ctx.weights)
     return aggregate_mean(ctx.stacked, cout.mask, ctx.weights)
@@ -561,11 +517,8 @@ def aggregate_mean_stage(ctx: StageCtx, cout: CohortOut):
     summary="one M-H mixing step: per-learner output, not a single model",
     out="fleet"))
 def aggregate_mix_stage(ctx: StageCtx, cout: CohortOut):
-    """One Metropolis–Hastings mixing step over the neighborhood — a
-    per-leaf tensordot on the tree layout, ONE ``W @ X`` matmul on the
-    plane."""
-    if ctx.flat is not None:
-        return cout.aux["W"].astype(ctx.flat.dtype) @ ctx.flat
+    """One Metropolis–Hastings mixing step over the neighborhood: ``W @
+    X`` as one tensordot per leaf, on either layout."""
     return aggregate_mix(ctx.stacked, cout.aux["W"])
 
 
@@ -578,7 +531,7 @@ def commit_average(ctx: StageCtx, cout: CohortOut, mean, hot, nhot) -> SyncOut:
     reference moves whenever anybody was actually averaged."""
     m = ctx.m
     if cout.ideal:
-        newcfg = _broadcast_commit(ctx, mean, m)
+        newcfg = broadcast_model(mean, m)
         rec = CommRecord(
             model_up=jnp.int32(m), model_down=jnp.int32(m),
             messages=jnp.int32(0), syncs=jnp.int32(1),
@@ -681,6 +634,6 @@ def commit_mix(ctx: StageCtx, cout: CohortOut, mixed, hot, nhot) -> SyncOut:
         # one mixing step couples every reachable learner
         full_syncs=((edges > 0) & (edges == na * (na - 1)))
         .astype(jnp.int32))
-    return SyncOut(mixed, _ref_view(ctx), carried_v(ctx, cout), cout.rng,
+    return SyncOut(mixed, ctx.state.ref, carried_v(ctx, cout), cout.rng,
                    ctx.state.extra, rec, xfers_neighborhood(A),
                    zeros_i32(ctx.m))

@@ -23,11 +23,10 @@ each chunk:
 Every span of one chunk carries ``round=<the chunk's first round>``.
 The device side is named by ``jax.named_scope`` inside the programs
 (``repro.local_update``; ``repro.sync``, the sync gate and its cond,
-with the stages ``repro.sync.condition/cohort/aggregate/commit``;
-``repro.plane.ravel/unravel``): the names land in each HLO op's
-``op_name`` metadata, which a TPU profile shows as ``tf_op_name``. An
-op XLA creates without metadata (a layout copy, a multi-output fusion)
-carries no name.
+with the stages ``repro.sync.condition/cohort/aggregate/commit``): the
+names land in each HLO op's ``op_name`` metadata, which a TPU profile
+shows as ``tf_op_name``. An op XLA creates without metadata (a layout
+copy, a multi-output fusion) carries no name.
 
 ``trace_counts`` counts JAX's own trace and compile events per program
 (one ``jax.monitoring`` listener, registered at import); each event is

@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.analysis.hlo import parse_collectives, count_op
+from repro.analysis.hlo import count_op, loop_data_movement, parse_collectives
 from repro.launch import sharding as shd
 
 HLO_SAMPLE = """
@@ -62,6 +62,69 @@ def test_async_start_counted_once():
 def test_count_op():
     assert count_op(HLO_SAMPLE, "parameter") == 2
     assert count_op(HLO_SAMPLE, "all-gather") == 1
+
+
+HLO_LOOP = """
+HloModule jit_chunk
+
+%fused_dus (param_0: f32[8,64], param_1: f32[8,16], param_2: s32[]) -> f32[8,64] {
+  %param_0 = f32[8,64]{1,0} parameter(0)
+  %param_1 = f32[8,16]{1,0} parameter(1)
+  %bitcast = f32[8,16]{1,0} bitcast(%param_1)
+  %param_2 = s32[] parameter(2)
+  %constant = s32[] constant(0)
+  ROOT %dynamic-update-slice = f32[8,64]{1,0} dynamic-update-slice(%param_0, %bitcast, %constant, %param_2)
+}
+
+%fused_mul (param_0.1: f32[8,64]) -> f32[8,64] {
+  %param_0.1 = f32[8,64]{1,0} parameter(0)
+  ROOT %multiply = f32[8,64]{1,0} multiply(%param_0.1, %param_0.1)
+}
+
+%fused_bcast (param_0.2: f32[64]) -> f32[8,64] {
+  %param_0.2 = f32[64]{0} parameter(0)
+  ROOT %broadcast = f32[8,64]{1,0} broadcast(%param_0.2), dimensions={1}
+}
+
+%body (p: (f32[8,64], f32[64])) -> (f32[8,64], f32[64]) {
+  %p = (f32[8,64]{1,0}, f32[64]{0}) parameter(0)
+  %x = f32[8,64]{1,0} get-tuple-element(%p), index=0
+  %r = f32[64]{0} get-tuple-element(%p), index=1
+  %copy.1 = f32[8,64]{0,1} copy(%x)
+  %slice.2 = f32[8,16]{1,0} slice(%x), slice={[0:8], [0:16]}
+  %i = s32[] constant(1)
+  %fusion.3 = f32[8,64]{1,0} fusion(%x, %slice.2, %i), kind=kLoop, calls=%fused_dus
+  %fusion.4 = f32[8,64]{1,0} fusion(%fusion.3), kind=kLoop, calls=%fused_mul
+  %fusion.5 = f32[8,64]{1,0} fusion(%r), kind=kLoop, calls=%fused_bcast
+  ROOT %t = (f32[8,64]{1,0}, f32[64]{0}) tuple(%fusion.4, %r)
+}
+
+%cond (p.1: (f32[8,64], f32[64])) -> pred[] {
+  %p.1 = (f32[8,64]{1,0}, f32[64]{0}) parameter(0)
+  ROOT %c = pred[] constant(true)
+}
+
+ENTRY %main (a: f32[8,64], b: f32[64]) -> f32[8,64] {
+  %a = f32[8,64]{1,0} parameter(0)
+  %b = f32[64]{0} parameter(1)
+  %copy.9 = f32[8,64]{0,1} copy(%a)
+  %init = (f32[8,64]{1,0}, f32[64]{0}) tuple(%a, %b)
+  %w = (f32[8,64]{1,0}, f32[64]{0}) while(%init), condition=%cond, body=%body
+  ROOT %out = f32[8,64]{1,0} get-tuple-element(%w), index=0
+}
+"""
+
+
+def test_loop_data_movement_sees_fusions_that_only_move_data():
+    """Inside the loop body: the bare copy and slice, and the fusion of a
+    bitcast and a dynamic-update-slice; not the arithmetic fusion, not
+    the broadcast fusion (it writes new values), and not the copy
+    outside the loop."""
+    found = {(name, op): nbytes
+             for _, name, op, nbytes in loop_data_movement(HLO_LOOP)}
+    assert found == {("copy.1", "copy"): 8 * 64 * 4,
+                     ("slice.2", "slice"): 8 * 16 * 4,
+                     ("fusion.3", "fusion"): 8 * 64 * 4}
 
 
 # ---------------------------------------------------------------------------

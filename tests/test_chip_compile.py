@@ -1,7 +1,8 @@
-"""Compile-only rehearsals of the monitoring kernel for a described TPU
-v5e (``v5e:2x2``): the TPU compiler refuses here, without a chip, what
-the chip would refuse — misaligned tiles, VMEM overruns, a Mosaic kernel
-it cannot partition. Nothing runs; the programs are compiled from shapes.
+"""Compile-only rehearsals for a described TPU v5e (``v5e:2x2``): the TPU
+compiler refuses here, without a chip, what the chip would refuse —
+misaligned tiles, VMEM overruns, a Mosaic kernel it cannot partition —
+and shows the layout copies it would add. Nothing runs; the programs are
+compiled from shapes.
 
 The topology is described inside a module fixture (never at import), so
 every pytest worker collects the same tests and only the worker that
@@ -125,3 +126,46 @@ def test_sharded_round_updates_learners_per_shard(topo):
     with shard.use_fleet(fleet):
         text = jax.jit(dl._make_step()).lower(*args).compile().as_text()
     assert "all-to-all" not in text
+
+
+# ---------------------------------------------------------------------------
+# the fleet plane moves no fleet-sized data inside the round loop
+# ---------------------------------------------------------------------------
+
+def test_flat_chunk_moves_no_fleet_sized_copies(one_chip):
+    """The engine's chunk for the MNIST CNN under layout="flat" (dynamic,
+    b=1, every round a full sync): inside the scan no copy, concatenate,
+    slice, pad or dynamic-update-slice the size of the fleet, bare or as
+    a fusion of nothing but data movement — the sync stages run on the
+    stacked leaves, so the fleet is never concatenated into one (m, P)
+    matrix and never relaid out between the local update and the
+    sync."""
+    from repro.analysis.hlo import loop_data_movement
+    from repro.config import ProtocolConfig, TrainConfig, get_arch
+    from repro.core.protocol import DecentralizedLearner
+    from repro.data.pipeline import LearnerStreams
+    from repro.data.synthetic import SyntheticMNIST
+    from repro.models.cnn import cnn_loss, init_cnn_params
+
+    cfg, m = get_arch("mnist_cnn"), 16
+    proto = ProtocolConfig(kind="dynamic", b=1, delta=1e-9,
+                           augmentation="max_distance", layout="flat")
+    dl = DecentralizedLearner(
+        lambda p, b: cnn_loss(cfg, p, b), lambda k: init_cnn_params(cfg, k),
+        m, proto, TrainConfig(optimizer="sgd", learning_rate=0.05))
+    batches = LearnerStreams(SyntheticMNIST(seed=0, image_size=28), m,
+                             batch=10, seed=0).next_chunk(4)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x),
+                                    sharding=one_chip)
+
+    args = jax.tree.map(on_chip, (dl.params, dl.opt_state, dl.sync_state,
+                                  batches))
+    text = jax.jit(dl._make_chunk()).lower(*args).compile().as_text()
+    assert " while(" in text
+    # m x 1,000,000 f32 elements: below one model per learner, above any
+    # activation or batch of this fleet
+    fleet_sized = [op for op in loop_data_movement(text)
+                   if op[3] >= m * 1_000_000 * 4]
+    assert fleet_sized == []

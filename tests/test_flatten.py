@@ -1,6 +1,6 @@
-"""The flat fleet-plane (ISSUE 5): adapter round-trips, layout="flat"
-equivalence against the default tree layout for every preset, and the
-engine/hierarchy integration.
+"""The fleet plane: the blocked plane's round and balancing cohort,
+layout="flat" equivalence against the default tree layout for every
+preset, and the engine/hierarchy integration.
 
 The equivalence contract under test is the acceptance criterion:
 ``layout="tree"`` stays bitwise (the golden regression in
@@ -20,7 +20,6 @@ from hypothesis_compat import given, settings, st
 from repro.config import (
     HierarchyConfig, NetworkConfig, ProtocolConfig, TrainConfig, get_arch,
 )
-from repro.core import flatten
 from repro.core import operators as ops
 from repro.core.divergence import (
     per_learner_sq_distance, per_learner_sq_distance_flat, tree_mean,
@@ -36,18 +35,38 @@ from conftest import make_stacked
 
 
 # ---------------------------------------------------------------------------
-# adapter: ravel/unravel round trips
+# the blocked plane: the stages run on the stacked leaves themselves
 # ---------------------------------------------------------------------------
 
 _FLOAT_DTYPES = (jnp.float32, jnp.bfloat16, jnp.float16)
 
+# primitives that would join or cut the leaves into one (m, P) matrix;
+# the blocked plane needs none
+_JOINS = {"concatenate", "pad", "dynamic_update_slice"}
 
-@settings(max_examples=30, deadline=None)
-@given(seed=st.integers(0, 10_000), m=st.integers(1, 6),
-       nleaves=st.integers(1, 5), data=st.data())
-def test_ravel_unravel_round_trip(seed, m, nleaves, data):
-    """unravel(ravel(params)) == params, bitwise, over random model
-    pytrees (mixed float dtypes, mixed ranks incl. scalars)."""
+
+def _all_primitives(jaxpr):
+    """Every primitive of a jaxpr, sub-jaxprs (jit, cond, while) included."""
+    found = set()
+    for e in jaxpr.eqns:
+        found.add(e.primitive.name)
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    found |= _all_primitives(inner)
+    return found
+
+
+@settings(max_examples=10, deadline=None)
+@given(seed=st.integers(0, 10_000), m=st.integers(2, 6),
+       nleaves=st.integers(1, 4), data=st.data())
+def test_plane_round_joins_no_leaves(seed, m, nleaves, data):
+    """The flat layout's dynamic round (condition, balancing cohort,
+    aggregate, commit) over random model pytrees (mixed float dtypes,
+    mixed ranks incl. scalars) traces to no concatenate, pad or
+    dynamic-update-slice, and commits each leaf in its own shape and
+    dtype."""
     key = jax.random.PRNGKey(seed)
     tree = {}
     for i in range(nleaves):
@@ -56,49 +75,111 @@ def test_ravel_unravel_round_trip(seed, m, nleaves, data):
         dtype = data.draw(st.sampled_from(_FLOAT_DTYPES))
         key, sub = jax.random.split(key)
         tree[f"w{i}"] = jax.random.normal(sub, (m,) + shape, dtype)
-    adapter = flatten.fleet_adapter(tree)
-    X = adapter.ravel(tree)
-    assert X.shape == (m, adapter.P)
-    back = adapter.unravel(X)
-    assert jax.tree.structure(back) == jax.tree.structure(tree)
-    for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(back)):
+    spec = PROTOCOLS["dynamic"].with_params(b=1, delta=1e-3, layout="flat")
+    state = ops.init_state(tree_mean(tree), seed)
+    round_fn = spec.compile()
+    prims = _all_primitives(jax.make_jaxpr(round_fn)(tree, state).jaxpr)
+    assert "while" in prims          # the balancing loop was traced
+    assert not prims & _JOINS, prims & _JOINS
+    out = round_fn(tree, state).params
+    assert jax.tree.structure(out) == jax.tree.structure(tree)
+    for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(out)):
         assert a.dtype == b.dtype and a.shape == b.shape
-        assert np.array_equal(np.asarray(a, np.float32),
-                              np.asarray(b, np.float32))
-    # the single-model view round-trips too
-    model = jax.tree.map(lambda x: x[0], tree)
-    r = adapter.ravel_model(model)
-    back1 = adapter.unravel_model(r)
-    for a, b in zip(jax.tree.leaves(model), jax.tree.leaves(back1)):
-        assert np.array_equal(np.asarray(a, np.float32),
-                              np.asarray(b, np.float32))
 
 
-def test_adapter_is_cached_and_rejects_non_float():
-    a = make_stacked(jax.random.PRNGKey(0), 4)
-    b = make_stacked(jax.random.PRNGKey(1), 4)   # same structure
-    assert flatten.fleet_adapter(a) is flatten.fleet_adapter(b)
-    with pytest.raises(TypeError):
-        flatten.fleet_adapter({"n": jnp.zeros((4, 3), jnp.int32)})
-    with pytest.raises(ValueError):
-        flatten.fleet_adapter({})
+def test_plane_pins_are_the_identity_off_mesh():
+    """With no active fleet the blocked plane is the configuration itself:
+    ``pin_rows`` returns the very leaves it was given, bitwise, and
+    traces to no equation at all."""
+    from repro.core.sync.spec import pin_rows
+    stacked = make_stacked(jax.random.PRNGKey(0), 4)
+    pinned = pin_rows(stacked)
+    for a, b in zip(jax.tree.leaves(stacked), jax.tree.leaves(pinned)):
+        assert a is b
+    assert jax.make_jaxpr(pin_rows)(stacked).eqns == []
 
 
 def test_flat_distances_match_tree_distances():
+    """The condition's distances under the flat layout equal the tree
+    layout's bitwise (the same per-leaf reduction on the same leaves),
+    and the Pallas row kernel over the same rows concatenated into one
+    (m, P) matrix agrees to rounding."""
     stacked = make_stacked(jax.random.PRNGKey(2), 5)
     ref = tree_mean(stacked)
-    adapter = flatten.fleet_adapter(stacked)
     want = per_learner_sq_distance(stacked, ref)
-    got = per_learner_sq_distance_flat(adapter.ravel(stacked),
-                                       adapter.ravel_model(ref))
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-6)
-    # forcing the Pallas kernel (interpret mode on CPU) agrees too
-    got_k = per_learner_sq_distance_flat(adapter.ravel(stacked),
-                                         adapter.ravel_model(ref),
-                                         use_kernel=True)
+    dists = {}
+    for layout in ("tree", "flat"):
+        spec = PROTOCOLS["dynamic"].with_params(b=1, delta=0.05,
+                                                layout=layout)
+        ctx = stages.StageCtx(
+            params=spec.resolved_params(), stacked=stacked,
+            state=ops.init_state(ref), weights=None, active=None,
+            adjacency=None, m=5, t=jnp.int32(1), reach=jnp.ones(5, bool),
+            plane=layout == "flat")
+        dists[layout] = stages._divergence_condition(ctx)[2]["dists"]
+    assert np.array_equal(np.asarray(dists["flat"]), np.asarray(want))
+    assert np.array_equal(np.asarray(dists["tree"]), np.asarray(want))
+    X = jnp.concatenate([x.reshape(5, -1) for x in jax.tree.leaves(stacked)],
+                        axis=1)
+    r = jnp.concatenate([x.reshape(-1) for x in jax.tree.leaves(ref)])
+    got_k = per_learner_sq_distance_flat(X, r, use_kernel=True)
     np.testing.assert_allclose(np.asarray(got_k), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
+
+
+def _mixed_fleet(m=6, seed=3):
+    """A mixed f32 + bf16 fleet with ranks 1-3 and its mean."""
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+    stacked = {"w": jax.random.normal(k1, (m, 4, 3), jnp.float32),
+               "b": jax.random.normal(k2, (m, 5), jnp.bfloat16),
+               "s": 3.0 * jax.random.normal(k3, (m,), jnp.float32)}
+    return stacked, tree_mean(stacked)
+
+
+def _assert_rows_close(a, b):
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        assert x.dtype == y.dtype and x.shape == y.shape
+        np.testing.assert_allclose(np.asarray(x, np.float32),
+                                   np.asarray(y, np.float32),
+                                   rtol=1e-2 if x.dtype == jnp.bfloat16
+                                   else 1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("case", [
+    "balanced_max_distance", "balanced_random", "balanced_weighted",
+    "balanced_all", "balanced_own_priority", "balanced_unreachable",
+])
+def test_blocked_primitives_match_tree_forms(case):
+    """The blocked plane's balancing cohort — an incremental running row
+    sum, one row added per step — equals the tree form's full
+    re-aggregation on a mixed f32 + bf16 fleet: the same cohort and the
+    same mean in the leaf dtypes."""
+    m = 6
+    stacked, ref = _mixed_fleet(m)
+    weights = jnp.arange(1.0, m + 1.0)
+    aug = {"balanced_random": "random",
+           "balanced_all": "all"}.get(case, "max_distance")
+    w = weights if case in ("balanced_weighted", "balanced_all") else None
+    reach = (jnp.asarray([1, 1, 0, 1, 1, 1], bool)
+             if case == "balanced_unreachable" else None)
+    dists = per_learner_sq_distance(stacked, ref)
+    # the two farthest learners violate; the safe zone takes more
+    delta = float(np.median(np.asarray(dists))) / 10
+    violated = dists > jnp.sort(dists)[-3]
+    if reach is not None:
+        violated = violated & reach
+    given = None if case == "balanced_own_priority" else dists
+    rng = jax.random.PRNGKey(1)
+    t_mask, t_mean = stages.cohort_balanced(
+        delta, aug, stacked, ref, violated, rng, w, reach, dists=given)
+    f_mask, f_mean = stages.cohort_balanced_flat(
+        delta, aug, stacked, ref, violated, rng, w, reach, dists=given)
+    assert np.array_equal(np.asarray(t_mask), np.asarray(f_mask))
+    # the augmentation ran: the cohort grew past the violators
+    assert int(jnp.sum(f_mask)) > int(jnp.sum(violated))
+    if reach is not None:
+        assert not bool(f_mask[2])
+    _assert_rows_close(f_mean, t_mean)
 
 
 # ---------------------------------------------------------------------------
@@ -144,7 +225,7 @@ def test_flat_operator_matches_tree_operator(kind, m, seed, mask_bits,
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-6)
     # learners the tree layout left untouched are BITWISE untouched on
-    # the flat layout too (ravel/unravel is reshape-only, no arithmetic)
+    # the flat layout too (the blocked plane moves no data)
     untouched = np.flatnonzero(
         (np.asarray(t.xfers) == 0) & (np.asarray(t.link_msgs) == 0)
         & ~np.asarray(active))

@@ -1,5 +1,5 @@
 """The device-sharded fleet plane (ISSUE 8): ``layout="sharded"`` is the
-flat ``(m, P)`` plane with the learner axis split over a device mesh —
+blocked fleet plane with the learner axis split over a device mesh —
 same ``ProtocolSpec`` compile, third execution backend.
 
 The equivalence contract under test is the acceptance criterion: for
@@ -32,7 +32,7 @@ from repro.config import (
 from repro.core import shard
 from repro.core.protocol import DecentralizedLearner
 from repro.core.sync.spec import (
-    LAYOUTS, PLANE_LAYOUTS, ProtocolSpec, resolve_spec,
+    LAYOUTS, PLANE_LAYOUTS, ProtocolSpec, pin_rows, resolve_spec,
 )
 from repro.data.pipeline import LearnerStreams
 from repro.data.synthetic import GraphicalModelStream
@@ -157,6 +157,41 @@ def test_kernel_distances_per_shard_match_dense():
     want = per_learner_sq_distance_flat(X, r, use_kernel=False)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5)
+
+
+def test_balancing_cohort_per_shard_matches_off_mesh():
+    """Under an active fleet the balancing loop's incremental running
+    sum adds rows of learner-sharded blocks: the same cohort, bitwise,
+    and the same mean as off the mesh."""
+    from repro.core.divergence import per_learner_sq_distance, tree_mean
+    from repro.core.sync import stages
+    m = 4 * N_DEV
+    fs = shard.fleet_sharding(m, 0)
+    k1, k2 = jax.random.split(jax.random.PRNGKey(5))
+    X = {"w": jax.device_put(jax.random.normal(k1, (m, 3, 4)),
+                             fs.row_sharding(3)),
+         "b": jax.device_put(jax.random.normal(k2, (m, 5), jnp.bfloat16),
+                             fs.row_sharding(2))}
+    ref = tree_mean(X)
+    dists = per_learner_sq_distance(X, ref)
+    violated = dists >= jnp.max(dists)
+    delta = float(jnp.median(dists)) / 10
+
+    def balance(X, rng):
+        return stages.cohort_balanced_flat(
+            delta, "max_distance", pin_rows(X), ref, violated, rng)
+
+    rng = jax.random.PRNGKey(0)
+    with shard.use_fleet(fs):
+        mask, mean = jax.jit(balance)(X, rng)
+    want_mask, want_mean = balance(X, rng)
+    assert np.array_equal(np.asarray(mask), np.asarray(want_mask))
+    assert int(jnp.sum(mask)) > 1
+    for name in X:
+        assert mean[name].dtype == X[name].dtype
+        np.testing.assert_allclose(np.asarray(mean[name], np.float32),
+                                   np.asarray(want_mean[name], np.float32),
+                                   rtol=1e-2, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------
