@@ -49,7 +49,8 @@ def variant_readings(cell, seed: int, variants, rounds=None):
     rounds = rounds or int(tr["chunk"])
 
     def fleet(**kw):
-        return reference.run_fleet(cfg, tr, data.make(seed, cfg["data"]["args"]),
+        return reference.run_fleet(cell.member, cfg, tr,
+                                   data.make(seed, cfg["data"]["args"]),
                                    seed, rounds, **kw)
 
     ref = fleet()

@@ -1,5 +1,5 @@
-"""Operations and bytes counted from shapes, for utilisation and
-roofline shares.
+"""Operations and weights of the CNN members, counted from their
+``cnn_spec``, for utilisation.
 
 ``model_flops_per_sample`` counts what one learner's SGD step requires
 per training sample: the forward pass, the weight gradients, and the
@@ -70,8 +70,3 @@ def model_flops_per_sample(spec, input_shape) -> int:
     input_grads = sum(macs[1:])
     return 2 * (forward + weight_grads + input_grads)
 
-
-def sqdist_rows_bytes(rows: int, params: int, itemsize: int = 4) -> int:
-    """HBM bytes one ``sqdist_rows`` call must move: the (rows, P) plane
-    and the reference row in, one distance per row out."""
-    return (rows * params + params + rows) * itemsize

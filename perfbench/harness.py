@@ -2,9 +2,12 @@
 
 A cell (an entry of ``workloads`` in ``BENCHMARK.json``) names a model
 configuration (``configs/<config>.json``) and a traffic mix
-(``traffic/<traffic>.json``); the limits of its check are in
-``limits/<cell>.json`` and every metric is read by ``metrics/<name>.py``.
-Nothing here names a cell, a configuration or a metric.
+(``traffic/<traffic>.json``); the configuration's ``"member"`` key names
+the module of its model family (``members/<member>.py``), which gives the
+program's init and loss and the reference's; the limits of its check are
+in ``limits/<cell>.json`` and every metric is read by
+``metrics/<name>.py``. Nothing here names a cell, a configuration, a
+model family or a metric.
 
 The window drives the program's own entry point,
 ``repro.train.loop.run_protocol_training``, which builds a fleet, runs
@@ -45,6 +48,8 @@ import time
 from typing import Callable, Optional
 
 import numpy as np
+
+from perfbench import members
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -88,6 +93,7 @@ class Cell:
         entry = {c["name"]: c for c in bench["configs"]}[
             self.workload["config"]]
         self.config = _load(os.path.join(root, entry["file"]))
+        self.member = members.load(self.config, entry["name"])
         self.traffic = _load(os.path.join(
             root, "perfbench", "traffic", self.workload["traffic"] + ".json"))
         limits = os.path.join(root, "perfbench", "limits", name + ".json")
@@ -187,15 +193,6 @@ def _memory_peak(devices) -> Optional[int]:
     return int(max(peaks)) if peaks else None
 
 
-def _model_config(cfg: dict):
-    from repro.config import ModelConfig
-    return ModelConfig(
-        name=cfg["name"], family="cnn", num_layers=0, d_model=0,
-        cnn_spec=tuple(tuple(layer) for layer in cfg["cnn_spec"]),
-        input_shape=tuple(cfg["input_shape"]),
-        num_outputs=int(cfg["num_outputs"]), source=cfg["source"])
-
-
 def _program_state(params, w0_model):
     """The leaf names; per leaf, ||theta_i - theta_0|| over the whole
     fleet; and the fleet's spread sum_i ||theta_i - mean theta||^2.
@@ -280,7 +277,6 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
 
     from repro.compile_cache import enable_compile_cache
     from repro.config import ProtocolConfig, TrainConfig
-    from repro.models.cnn import cnn_loss, init_cnn_params
     from repro.train.loop import run_protocol_training
 
     from perfbench import reference
@@ -299,7 +295,6 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
 
     tr, cfg_json = cell.traffic, cell.config
     seed = fold_seed(seed)
-    cfg = _model_config(cfg_json)
     data = importlib.import_module(
         f"perfbench.data.{cfg_json['data']['generator']}")
     protocol = ProtocolConfig(
@@ -308,10 +303,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         shard_devices=int(tr["shard_devices"]))
     train = TrainConfig(optimizer=tr["optimizer"],
                         learning_rate=float(tr["learning_rate"]))
-    init_fn = jax.jit(lambda k: init_cnn_params(cfg, k))
-
-    def loss_fn(params, batch):
-        return cnn_loss(cfg, params, batch)
+    init_fn, loss_fn = cell.member.program(cfg_json)
 
     run = Run(cell)
     run.devices = devices
@@ -335,7 +327,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     target = min(seconds, TRACE_SECONDS) if trace else seconds
     chunks = 1 + max(2, min(100_000, math.ceil(target / chunk_s)))
     k_init = jax.random.split(jax.random.PRNGKey(seed), 3)[0]
-    w0 = reference.init_model(cfg.cnn_spec, cfg.input_shape, k_init)
+    w0 = cell.member.reference_init(cfg_json, k_init)
     at_start = {}
     trace_dir = None
     if trace:
@@ -392,8 +384,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
 
     # 3. the check, once the program's state is freed
     ref = reference.run_fleet(
-        cfg_json, tr, data.make(seed, cfg_json["data"]["args"]), seed,
-        at_start["rounds"])
+        cell.member, cfg_json, tr, data.make(seed, cfg_json["data"]["args"]),
+        seed, at_start["rounds"])
     checks = compare(cell, program, ref)
     correct = failed == 0 and passes(checks)
 

@@ -11,21 +11,32 @@ Delta of it (or every learner is in); the cohort adopts its mean; a sync
 of the whole fleet moves the reference; a violation counter that reaches
 m forces a full sync.
 
+The member model comes from the cell's member module
+(``perfbench/members/``): its ``reference_init`` and ``reference_loss``.
 Weights and data come from the seed exactly as the program's entry point
-derives them (``PRNGKey(seed)`` split in three, the first for a Glorot
-initialisation; ``PRNGKey(seed ^ 0x5EED)`` split once per round and once
-per learner for the streams), so the reference and the program start from
-the same fleet and see the same batches.
+derives them (``PRNGKey(seed)`` split in three, the first for the
+member's initialisation; ``PRNGKey(seed ^ 0x5EED)`` split once per round
+and once per learner for the streams), so the reference and the program
+start from the same fleet and see the same batches.
 
-The fleet is held as one (m, P) plane and updated in blocks of learners,
-so that a fleet of 1,024 MNIST learners fits one chip. ``dtype`` float32
-runs at full matmul precision (the reference); bfloat16 is the control,
-the precision below the configuration's float32.
+The fleet is held as the stacked leaves, each leaf ``(m, *leaf.shape)``,
+as the program holds it; no array joins the leaves. A learner's squared
+distance is a float32 sum over leaves; cohort means, commits, the spread
+and the change norms are taken leaf by leaf. Learners are updated, and
+their distances taken, ``member.LEARNER_BLOCK`` at a time. Peak device
+memory is the fleet, plus at most three model-sized arrays at once (the
+reference ``r``, the start ``w0``, and the cohort's running sum or its
+mean), plus one learner block's gradients and activations. (The planted
+fault ``groups`` > 1 may hold one more fleet-sized temporary.)
+
+``dtype`` float32 runs at full matmul precision (the reference);
+bfloat16 is the control, the precision below the configuration's
+float32: it casts the fleet's leaves and the batch's floating leaves,
+and leaves integer leaves (labels, tokens) as they are.
 """
 from __future__ import annotations
 
 import functools
-import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -33,136 +44,6 @@ import jax.numpy as jnp
 import numpy as np
 
 STREAM_SALT = 0x5EED
-
-
-# ---------------------------------------------------------------------------
-# the member model
-# ---------------------------------------------------------------------------
-
-def init_model(spec, input_shape, key, dtype=jnp.float32):
-    """Glorot-uniform weights and zero biases, one key split per layer
-    with parameters. Returns ``{"layers": [per-layer dict]}``."""
-    if len(input_shape) == 1:
-        h = w = c = 0
-        flat = input_shape[0]
-    else:
-        h, w, c = input_shape
-        flat = None
-    layers = []
-    for layer in spec:
-        kind = layer[0]
-        if kind == "conv":
-            _, c_out, k, s = layer
-            key, sub = jax.random.split(key)
-            lim = math.sqrt(6.0 / (k * k * (c + c_out)))
-            layers.append({
-                "w": jax.random.uniform(sub, (k, k, c, c_out), dtype,
-                                        -lim, lim),
-                "b": jnp.zeros((c_out,), dtype)})
-            h, w, c = (h - k) // s + 1, (w - k) // s + 1, c_out
-        elif kind == "pool":
-            layers.append({})
-            h, w = h // layer[1], w // layer[1]
-        elif kind == "flatten":
-            layers.append({})
-            if c:
-                flat = h * w * c
-        elif kind == "dense":
-            key, sub = jax.random.split(key)
-            lim = math.sqrt(6.0 / (flat + layer[1]))
-            layers.append({
-                "w": jax.random.uniform(sub, (flat, layer[1]), dtype,
-                                        -lim, lim),
-                "b": jnp.zeros((layer[1],), dtype)})
-            flat = layer[1]
-        elif kind == "dropout":
-            layers.append({})
-        else:
-            raise ValueError(f"unknown layer {layer!r}")
-    return {"layers": layers}
-
-
-def forward(spec, params, x):
-    """(B, H, W, C) or (B, d) -> (B, outputs). Dropout is the identity:
-    the fleet's loss is evaluated without a dropout key."""
-    n_dense = sum(1 for layer in spec if layer[0] == "dense")
-    seen = 0
-    for layer, p in zip(spec, params["layers"]):
-        kind = layer[0]
-        if kind == "conv":
-            s = layer[3]
-            x = jax.lax.conv_general_dilated(
-                x, p["w"], window_strides=(s, s), padding="VALID",
-                dimension_numbers=("NHWC", "HWIO", "NHWC"))
-            x = jax.nn.relu(x + p["b"])
-        elif kind == "pool":
-            k = layer[1]
-            x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max,
-                                      (1, k, k, 1), (1, k, k, 1), "VALID")
-        elif kind == "flatten":
-            x = x.reshape(x.shape[0], -1)
-        elif kind == "dense":
-            seen += 1
-            x = x @ p["w"] + p["b"]
-            if seen < n_dense:
-                x = jax.nn.relu(x)
-    return x
-
-
-def loss(spec, num_outputs, params, batch, half_batch=False):
-    """Squared error for a single output, else softmax cross-entropy
-    (log-softmax in float32). ``half_batch`` is a planted fault: the mean
-    over the first half of the batch only."""
-    x, y = batch["x"], batch["y"]
-    if half_batch:
-        x, y = x[: x.shape[0] // 2], y[: y.shape[0] // 2]
-    out = forward(spec, params, x)
-    if num_outputs == 1:
-        return jnp.mean(jnp.square(out[:, 0].astype(jnp.float32)
-                                   - y.astype(jnp.float32)))
-    lp = jax.nn.log_softmax(out.astype(jnp.float32), axis=-1)
-    return -jnp.mean(jnp.take_along_axis(lp, y[:, None], axis=-1))
-
-
-# ---------------------------------------------------------------------------
-# the fleet plane
-# ---------------------------------------------------------------------------
-
-class Layout(NamedTuple):
-    names: tuple      # leaf names, as jax.tree_util.keystr gives them
-    shapes: tuple
-    offsets: tuple    # start column of each leaf in a plane row
-    size: int         # P
-    treedef: object
-
-
-def layout_of(model) -> Layout:
-    flat, treedef = jax.tree_util.tree_flatten_with_path(model)
-    names = tuple(jax.tree_util.keystr(path) for path, _ in flat)
-    shapes = tuple(tuple(x.shape) for _, x in flat)
-    sizes = [int(np.prod(s)) for s in shapes]
-    offsets = tuple(int(o) for o in np.cumsum([0] + sizes[:-1]))
-    return Layout(names, shapes, offsets, int(sum(sizes)), treedef)
-
-
-def ravel_rows(lay: Layout, tree):
-    leaves = jax.tree_util.tree_leaves(tree)
-    n = leaves[0].shape[0]
-    return jnp.concatenate([x.reshape(n, -1) for x in leaves], axis=1)
-
-
-def unravel_rows(lay: Layout, rows):
-    n = rows.shape[0]
-    leaves = [rows[:, o:o + int(np.prod(s))].reshape((n,) + s)
-              for o, s in zip(lay.offsets, lay.shapes)]
-    return jax.tree_util.tree_unflatten(lay.treedef, leaves)
-
-
-def leaf_sq_norms(lay: Layout, rows):
-    """Per-leaf sum of squares over a block of plane rows -> (leaves,)."""
-    sq = rows.astype(jnp.float32) ** 2
-    return jnp.stack([jnp.sum(sq[:, o:o + int(np.prod(s))])
-                      for o, s in zip(lay.offsets, lay.shapes)])
 
 
 # ---------------------------------------------------------------------------
@@ -181,59 +62,83 @@ def round_batches(source, m: int, batch: int, seed: int, rounds: int):
         yield draw(jax.random.split(sub, m))
 
 
+def _cast_batch(bt, dtype):
+    """The batch's floating leaves in ``dtype``; integer leaves as they
+    are."""
+    return jax.tree.map(
+        lambda a: a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating)
+        else a, bt)
+
+
 # ---------------------------------------------------------------------------
-# Algorithm 1
+# per-leaf fleet arithmetic
 # ---------------------------------------------------------------------------
 
-def _row_dists(X, r, block: int):
-    """Squared distance of each plane row to r, one block of rows at a
-    time so that no plane-sized temporary is made."""
-    m, p = X.shape
-    blocks = X.reshape(m // block, block, p)
-
-    def one(xb):
-        d = xb.astype(jnp.float32) - r.astype(jnp.float32)[None]
-        return jnp.sum(d * d, axis=1)
-
-    return jax.lax.map(one, blocks).reshape(m)
+def _blocks(x, block: int):
+    """(m, ...) -> (m // block, block, ...): a view, no data moves."""
+    return x.reshape((x.shape[0] // block, block) + x.shape[1:])
 
 
-def _cohort_mean(X, mask, groups: int):
-    """Mean of the cohort's rows. ``groups`` > 1 is a planted fault: the
-    fleet is cut into that many contiguous groups (the shards of a
-    sharded fleet) and each member takes its own group's cohort mean,
-    as if the exchange between chips were left out."""
-    w = mask.astype(X.dtype)
-    if groups == 1:
-        return (w @ X) / jnp.maximum(jnp.sum(w), 1)
-    m = X.shape[0]
-    g = w.reshape(groups, m // groups)
-    Xg = X.reshape(groups, m // groups, X.shape[1])
-    means = jnp.einsum("gk,gkp->gp", g, Xg) / jnp.maximum(
-        jnp.sum(g, axis=1, keepdims=True), 1)
-    return jnp.repeat(means, m // groups, axis=0)
+def _sq_dists(fleet, model, block: int):
+    """(m,) float32: each learner's squared distance to ``model``, summed
+    over leaves, one block of learners at a time so that no fleet-sized
+    temporary is made."""
+    total = 0.0
+    for x, r in zip(jax.tree.leaves(fleet), jax.tree.leaves(model)):
+        r32 = r.astype(jnp.float32)
+
+        def one(xb, r32=r32):
+            d = xb.astype(jnp.float32) - r32[None]
+            return jnp.sum(d * d, axis=tuple(range(1, xb.ndim)))
+
+        total = total + jax.lax.map(one, _blocks(x, block)).reshape(-1)
+    return total
 
 
-def sync_round(X, r, v, *, delta: float, block: int, groups: int = 1):
+def _cohort_mean(fleet, mask, groups: int):
+    """Per leaf, the mean of the cohort's learners. ``groups`` > 1 is a
+    planted fault: the fleet is cut into that many contiguous groups (the
+    shards of a sharded fleet) and each group's cohort mean is taken on
+    its own, as if the exchange between chips were left out; each leaf's
+    mean is then ``(groups, *leaf.shape)``."""
+    def one(x):
+        w = mask.astype(x.dtype)
+        if groups == 1:
+            return jnp.tensordot(w, x, axes=1) / jnp.maximum(jnp.sum(w), 1)
+        g = w.reshape(groups, -1)
+        means = jnp.einsum("gk,gk...->g...", g, _blocks(x, g.shape[1]))
+        n = jnp.maximum(jnp.sum(g, axis=1), 1)
+        return means / n.reshape((groups,) + (1,) * (x.ndim - 1))
+    return jax.tree.map(one, fleet)
+
+
+def _where_rows(mask, new, old):
+    return jnp.where(mask.reshape((-1,) + (1,) * (old.ndim - 1)), new, old)
+
+
+def sync_round(fleet, r, v, *, delta: float, block: int, groups: int = 1):
     """One check of Algorithm 1 with the max-distance augmentation.
-    Returns (X, r, v, synced, full, cohort size)."""
-    m = X.shape[0]
-    dists = _row_dists(X, r, block)
+    Returns (fleet, r, v, synced, full, cohort size)."""
+    m = jax.tree.leaves(fleet)[0].shape[0]
+    dists = _sq_dists(fleet, r, block)
     violated = dists > delta
     nhot = jnp.sum(violated).astype(jnp.int32)
 
     def safe(s, ws):
-        d = s / jnp.where(ws > 0, ws, 1) - r
-        return jnp.sum(d * d)
+        ws = jnp.where(ws > 0, ws, 1)
+        return sum(jnp.sum(jnp.square(a.astype(jnp.float32) / ws
+                                      - b.astype(jnp.float32)))
+                   for a, b in zip(jax.tree.leaves(s), jax.tree.leaves(r)))
 
-    def fire(X, r, v):
+    def fire(fleet, r, v):
         v_new = v + nhot
         force = v_new >= m
         mask0 = jnp.where(force, jnp.ones_like(violated), violated)
         v_reset = jnp.where(force, 0, v_new)
-        w0 = mask0.astype(X.dtype)
-        s0 = w0 @ X
-        ws0 = jnp.sum(w0)
+        s0 = jax.tree.map(
+            lambda x: jnp.tensordot(mask0.astype(x.dtype), x, axes=1),
+            fleet)
+        ws0 = jnp.sum(mask0).astype(jnp.float32)
 
         def cond(c):
             mask, _, _, d = c
@@ -243,27 +148,37 @@ def sync_round(X, r, v, *, delta: float, block: int, groups: int = 1):
             mask, s, ws, _ = c
             nxt = jnp.argmax(jnp.where(mask, -jnp.inf, dists))
             mask = mask.at[nxt].set(True)
-            s = s + X[nxt]
+            s = jax.tree.map(lambda a, x: a + x[nxt], s, fleet)
             ws = ws + 1
             return mask, s, ws, safe(s, ws)
 
         mask, _, _, _ = jax.lax.while_loop(
             cond, body, (mask0, s0, ws0, safe(s0, ws0)))
         full = jnp.all(mask)
-        mean = _cohort_mean(X, mask, groups)
+        mean = _cohort_mean(fleet, mask, groups)
         if groups == 1:
-            X = jnp.where(mask[:, None], mean[None], X)
-            r = jnp.where(full, mean, r)
+            fleet = jax.tree.map(lambda x, mu: _where_rows(mask, mu[None], x),
+                                 fleet, mean)
+            r = jax.tree.map(lambda mu, ri: jnp.where(full, mu, ri), mean, r)
         else:
-            X = jnp.where(mask[:, None], mean, X)
-            r = jnp.where(full, jnp.mean(mean, axis=0), r)
+            # each learner adopts its own group's mean
+            def commit(x, mu):
+                k = mask.reshape((groups, m // groups) + (1,) * (x.ndim - 1))
+                return jnp.where(k, mu[:, None], _blocks(x, m // groups)
+                                 ).reshape(x.shape)
+
+            fleet = jax.tree.map(commit, fleet, mean)
+            r = jax.tree.map(
+                lambda mu, ri: jnp.where(full, jnp.mean(mu, axis=0), ri),
+                mean, r)
         v = jnp.where(full, 0, v_reset)
-        return X, r, v, jnp.int32(1), full, jnp.sum(mask).astype(jnp.int32)
+        return fleet, r, v, jnp.int32(1), full, jnp.sum(mask).astype(
+            jnp.int32)
 
-    def skip(X, r, v):
-        return X, r, v, jnp.int32(0), jnp.bool_(False), jnp.int32(0)
+    def skip(fleet, r, v):
+        return fleet, r, v, jnp.int32(0), jnp.bool_(False), jnp.int32(0)
 
-    return jax.lax.cond(nhot > 0, fire, skip, X, r, v)
+    return jax.lax.cond(nhot > 0, fire, skip, fleet, r, v)
 
 
 # ---------------------------------------------------------------------------
@@ -287,19 +202,18 @@ def _block_size(m: int, want: int) -> int:
     return b
 
 
-def run_fleet(model_cfg: dict, traffic: dict, source, seed: int,
+def run_fleet(member, model_cfg: dict, traffic: dict, source, seed: int,
               rounds: int, *, dtype=jnp.float32, fault: Optional[str] = None,
-              groups: int = 1, learner_block: int = 64) -> Readings:
-    """Drive the reference fleet for ``rounds`` rounds from ``seed``.
+              groups: int = 1,
+              learner_block: Optional[int] = None) -> Readings:
+    """Drive the reference fleet of ``member`` (a module of
+    ``perfbench/members/``) for ``rounds`` rounds from ``seed``.
 
     ``fault`` plants one of the faults the benchmark must catch, in the
     reference put in the program's place: ``"frozen"`` (a step that
     returns its state unchanged), ``"half_batch"`` (half of each batch
     left out, the mean taken over the rest); ``groups`` > 1 leaves out
     the exchange between that many shards."""
-    spec = tuple(tuple(layer) for layer in model_cfg["cnn_spec"])
-    input_shape = tuple(model_cfg["input_shape"])
-    num_outputs = int(model_cfg["num_outputs"])
     m, batch = int(traffic["m"]), int(traffic["batch"])
     lr, b, delta = (float(traffic["learning_rate"]), int(traffic["b"]),
                     float(traffic["delta"]))
@@ -310,67 +224,86 @@ def run_fleet(model_cfg: dict, traffic: dict, source, seed: int,
     precision = "highest" if dtype == jnp.float32 else "default"
 
     k_init, _, _ = jax.random.split(jax.random.PRNGKey(seed), 3)
-    model = init_model(spec, input_shape, k_init)       # float32, as made
-    lay = layout_of(model)
-    w0 = ravel_rows(lay, jax.tree.map(lambda x: x[None], model))[0]
-    w0 = w0.astype(dtype)
-    blk = _block_size(m, learner_block)
+    model = member.reference_init(model_cfg, k_init)    # float32, as made
+    flat, _ = jax.tree_util.tree_flatten_with_path(model)
+    names = tuple(jax.tree_util.keystr(path) for path, _ in flat)
+    sizes = np.asarray([int(np.prod(x.shape)) for _, x in flat])
+    w0 = jax.tree.map(lambda x: x.astype(dtype), model)
+    del model
+    blk = _block_size(m, learner_block or member.LEARNER_BLOCK)
     half = fault == "half_batch"
 
+    def one_loss(params, bt):
+        return member.reference_loss(model_cfg, params, bt, half_batch=half)
+
     @functools.partial(jax.jit, donate_argnums=0)
-    def update(X, start, xb, yb):
-        rows = jax.lax.dynamic_slice_in_dim(X, start, blk, 0)
-        params = unravel_rows(lay, rows)
-        losses, grads = jax.vmap(jax.value_and_grad(
-            lambda p, bt: loss(spec, num_outputs, p, bt, half)))(
-            params, {"x": xb, "y": yb})
-        g = ravel_rows(lay, grads).astype(dtype)
-        new = rows if fault == "frozen" else rows - jnp.asarray(lr, dtype) * g
-        X = jax.lax.dynamic_update_slice_in_dim(X, new, start, 0)
-        return X, losses.astype(jnp.float32), leaf_sq_norms(lay, g)
+    def update(fleet, start, bt):
+        def rows(x):
+            return jax.lax.dynamic_slice_in_dim(x, start, blk, 0)
+
+        params = jax.tree.map(rows, fleet)
+        losses, grads = jax.vmap(jax.value_and_grad(one_loss))(
+            params, jax.tree.map(rows, bt))
+        grads = jax.tree.map(lambda g, p: g.astype(p.dtype), grads, params)
+        if fault == "frozen":
+            new = params
+        else:
+            new = jax.tree.map(
+                lambda p, g: p - jnp.asarray(lr, p.dtype) * g, params, grads)
+        fleet = jax.tree.map(
+            lambda x, n: jax.lax.dynamic_update_slice_in_dim(x, n, start, 0),
+            fleet, new)
+        g_sq = jnp.stack([jnp.sum(jnp.square(g.astype(jnp.float32)))
+                          for g in jax.tree.leaves(grads)])
+        return fleet, losses.astype(jnp.float32), g_sq
 
     sync = jax.jit(functools.partial(
-        sync_round, delta=delta, block=_block_size(m, 64), groups=groups),
+        sync_round, delta=delta, block=blk, groups=groups),
         donate_argnums=0)
 
     @jax.jit
-    def change_sq(X, start):
-        rows = jax.lax.dynamic_slice_in_dim(X, start, blk, 0)
-        return leaf_sq_norms(lay, rows.astype(jnp.float32)
-                             - w0.astype(jnp.float32)[None])
+    def change_sq(fleet, w0):
+        """(leaves, blocks): per leaf and learner block, sum ||theta_i -
+        theta_0||^2."""
+        def leaf(x, w):
+            w32 = w.astype(jnp.float32)
+            return jax.lax.map(
+                lambda xb: jnp.sum(jnp.square(xb.astype(jnp.float32)
+                                              - w32[None])),
+                _blocks(x, blk))
+        return jnp.stack([leaf(x, w) for x, w in zip(jax.tree.leaves(fleet),
+                                                     jax.tree.leaves(w0))])
 
     @jax.jit
-    def spread(X):
-        mean = jnp.mean(X.astype(jnp.float32), axis=0)
-        return jnp.sum(_row_dists(X, mean, blk))
+    def spread(fleet):
+        mean = jax.tree.map(lambda x: jnp.mean(x.astype(jnp.float32), axis=0),
+                            fleet)
+        return jnp.sum(_sq_dists(fleet, mean, blk))
 
     round_loss, grad_sq = [], None
     ledger = {"syncs": 0, "full_syncs": 0, "model_up": 0}
     with jax.default_matmul_precision(precision):
-        X = jnp.broadcast_to(w0[None], (m, lay.size)).copy()
+        fleet = jax.tree.map(
+            lambda w: jnp.broadcast_to(w[None], (m,) + w.shape).copy(), w0)
         r, v = w0, jnp.int32(0)
         batches = round_batches(source, m, batch, seed, rounds)
         for t, bt in enumerate(batches, start=1):
-            x = bt["x"].astype(dtype) if dtype != jnp.float32 else bt["x"]
-            y = bt["y"]
+            if dtype != jnp.float32:
+                bt = _cast_batch(bt, dtype)
             total, gsq = 0.0, 0.0
             for start in range(0, m, blk):
-                X, losses, g2 = update(X, start, x[start:start + blk],
-                                       y[start:start + blk])
+                fleet, losses, g2 = update(fleet, start, bt)
                 total += float(np.sum(np.asarray(losses, np.float64)))
                 gsq = gsq + np.asarray(g2, np.float64)
             round_loss.append(total)
             if grad_sq is None:
                 grad_sq = gsq
             if t % b == 0:
-                X, r, v, fired, full, size = sync(X, r, v)
+                fleet, r, v, fired, full, size = sync(fleet, r, v)
                 ledger["syncs"] += int(fired)
                 ledger["full_syncs"] += int(full)
                 ledger["model_up"] += int(size)
-        change = sum(np.asarray(change_sq(X, s), np.float64)
-                     for s in range(0, m, blk))
-        fleet_spread = float(spread(X))
-    sizes = np.asarray([int(np.prod(sh)) for sh in lay.shapes])
-    return Readings(lay.names, sizes, np.asarray(round_loss),
-                    np.sqrt(change),
+        change = np.sum(np.asarray(change_sq(fleet, w0), np.float64), axis=1)
+        fleet_spread = float(spread(fleet))
+    return Readings(names, sizes, np.asarray(round_loss), np.sqrt(change),
                     np.sqrt(grad_sq), fleet_spread, ledger)

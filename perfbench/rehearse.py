@@ -42,11 +42,10 @@ def main(argv=None) -> int:
     from repro.core.protocol import DecentralizedLearner
     from repro.data.pipeline import LearnerStreams
     from repro.kernels import ops
-    from repro.models.cnn import cnn_loss, init_cnn_params
 
     cell = harness.Cell(args.workload)
     tr, cfg_json = cell.traffic, cell.config
-    cfg = harness._model_config(cfg_json)
+    init_fn, loss_fn = cell.member.program(cfg_json)
     M, chunk, batch = int(tr["m"]), int(tr["chunk"]), int(tr["batch"])
     sharded = tr["layout"] == "sharded"
     small = 8 * max(1, int(tr["shard_devices"]))
@@ -55,9 +54,9 @@ def main(argv=None) -> int:
                            augmentation=tr["augmentation"],
                            layout="flat" if sharded else tr["layout"])
     dl = DecentralizedLearner(
-        lambda p, b: cnn_loss(cfg, p, b), lambda k: init_cnn_params(cfg, k),
-        small, proto, TrainConfig(optimizer=tr["optimizer"],
-                                  learning_rate=float(tr["learning_rate"])))
+        loss_fn, init_fn, small, proto,
+        TrainConfig(optimizer=tr["optimizer"],
+                    learning_rate=float(tr["learning_rate"])))
     data = importlib.import_module(
         f"perfbench.data.{cfg_json['data']['generator']}")
     streams = LearnerStreams(data.make(0, cfg_json["data"]["args"]), M,

@@ -15,7 +15,8 @@ for p in (ROOT, os.path.join(ROOT, "src")):
         sys.path.insert(0, p)
 
 TINY_CONFIG = {
-    "name": "tiny_cnn", "source": "a small copy of mnist_cnn for the CPU",
+    "name": "tiny_cnn", "member": "cnn",
+    "source": "a small copy of mnist_cnn for the CPU",
     "cnn_spec": [["conv", 4, 3, 1], ["conv", 8, 3, 1], ["pool", 2],
                  ["flatten"], ["dense", 16], ["dense", 10]],
     "input_shape": [14, 14, 1], "num_outputs": 10,

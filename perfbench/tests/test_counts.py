@@ -1,4 +1,4 @@
-"""Operation, weight and byte counts against hand counts."""
+"""Operation and weight counts against hand counts."""
 import json
 import os
 
@@ -41,7 +41,3 @@ def test_deepdrive_flops_by_hand():
     assert want == 250_081_572                    # 250 MFLOP per sample
     assert flops.model_flops_per_sample(*_cfg("deepdrive_cnn")) == want
 
-
-def test_sqdist_rows_bytes():
-    assert flops.sqdist_rows_bytes(256, 1_199_882) == (
-        256 * 1_199_882 + 1_199_882 + 256) * 4

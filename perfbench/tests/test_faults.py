@@ -62,8 +62,9 @@ def test_half_the_batch_left_out(cell, monkeypatch):
 
 
 def test_half_the_cohort_keeps_its_own_model(cell, monkeypatch):
-    """The commit leaves the second half of the fleet's rows as they
-    were: the cohort's mean reaches only half of it."""
+    """The commit leaves the second half of the fleet's learners as they
+    were, leaf by leaf: the cohort's mean reaches only half of it."""
+    import jax
     import jax.numpy as jnp
 
     from repro.core.sync import stages
@@ -73,7 +74,12 @@ def test_half_the_cohort_keeps_its_own_model(cell, monkeypatch):
     def half(ctx, mask, mean):
         new = whole(ctx, mask, mean)
         keep = jnp.arange(ctx.m) >= ctx.m // 2
-        return jnp.where(keep[:, None], ctx.flat, new)
+
+        def leaf(old, committed):
+            k = keep.reshape((-1,) + (1,) * (old.ndim - 1))
+            return jnp.where(k, old, committed)
+
+        return jax.tree.map(leaf, ctx.stacked, new)
 
     monkeypatch.setattr(stages, "_select_commit", half)
     r = _run(cell)
@@ -91,23 +97,31 @@ from repro.compat import shard_map
 from repro.core import shard
 from repro.core.sync import stages
 
-plain = stages.flat_weighted_mean
+plain = stages.aggregate_mean
 
-def first_shard_only(X, w):
-    # the exchange between chips left out: each chip sums its own rows,
-    # and the commit takes chip 0's partial mean as the cohort's
+def first_shard_only(stacked, mask, weights=None):
+    # the exchange between chips left out: each chip sums its own
+    # learners, leaf by leaf, and the commit takes chip 0's partial mean
+    # as the cohort's
     fleet = shard.current_fleet()
-    if {plant} and fleet is not None and X.shape[0] == fleet.m:
-        def local(xs, ws):
-            ws = ws.astype(xs.dtype)
-            return (ws @ xs)[None], jnp.sum(ws)[None]
-        s, n = shard_map(local, mesh=fleet.mesh,
-                         in_specs=(P("fleet", None), P("fleet")),
-                         out_specs=(P("fleet"), P("fleet")))(X, w)
-        return s[0] / jnp.where(n[0] > 0, n[0], 1)
-    return plain(X, w)
+    if ({plant} and fleet is not None and weights is None
+            and mask.shape[0] == fleet.m):
+        def local(x, w):
+            w = w.astype(x.dtype)
+            return (jnp.tensordot(w, x, axes=1)[None], jnp.sum(w)[None])
 
-stages.flat_weighted_mean = first_shard_only
+        def leaf(x):
+            rest = (None,) * (x.ndim - 1)
+            s, n = shard_map(local, mesh=fleet.mesh,
+                             in_specs=(P("fleet", *rest), P("fleet")),
+                             out_specs=(P("fleet", *rest), P("fleet")))(
+                                 x, mask)
+            return s[0] / jnp.where(n[0] > 0, n[0], 1)
+
+        return jax.tree.map(leaf, stacked)
+    return plain(stacked, mask, weights)
+
+stages.aggregate_mean = first_shard_only
 cell = harness.Cell("tiny", root={root!r})
 r = harness.run_cell(cell, 2147483659, 0.5, False, t0, require_chip=False)
 print(json.dumps(r))
